@@ -83,8 +83,7 @@ def run(fast: bool = False, backend: str = None, prefix_cache: str = None):
     # oracle impl ignores it; impl="pallas" exercises it end-to-end)
     impl = "ref" if backend is None else "pallas"
     decode = jax.jit(lambda p, tok, st: model.decode_step(
-        p, tok, st, impl=impl, backend=backend,
-        interpret=True if backend is not None else None))
+        p, tok, st, impl=impl, backend=backend))
     forward = jax.jit(lambda p, toks: model.forward(p, toks))
 
     rows = []

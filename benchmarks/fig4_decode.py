@@ -46,7 +46,7 @@ def run(fast: bool = False, backend: str = None):
     # the kernel axis honours --backend (TPU scalar-prefetch pipeline or
     # GPU/Triton in-kernel gather; None → auto from the platform)
     pallas = jax.jit(lambda q, kp, vp, bt, l: decode_attention(
-        q, kp, vp, bt, l, impl="pallas", interpret=True, backend=backend))
+        q, kp, vp, bt, l, impl="pallas", backend=backend))
     contig = jax.jit(decode_attention_contiguous)
     bk = backend or "auto"
 
@@ -64,8 +64,8 @@ def run(fast: bool = False, backend: str = None):
 
         ks = jax.random.split(jax.random.PRNGKey(S), 5)
         q = jax.random.normal(ks[0], (B, H, D))
-        kp = jax.random.normal(ks[1], (B * mp, ps, Hkv, D))
-        vp = jax.random.normal(ks[2], (B * mp, ps, Hkv, D))
+        kp = jax.random.normal(ks[1], (B * mp, Hkv, ps, D))
+        vp = jax.random.normal(ks[2], (B * mp, Hkv, ps, D))
         bt = jnp.arange(B * mp, dtype=jnp.int32).reshape(B, mp)
         lens = jnp.full((B,), S, jnp.int32)
         kc = jax.random.normal(ks[3], (B, S, Hkv, D))

@@ -33,6 +33,8 @@ def main() -> None:
                          "(default: benches report both on and off rows)")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (fig3_latency, fig4_decode, fig12_memory,
                             mixed_batch, roofline, tbl_allocator,
                             tbl_decode_blocks, tbl_pagesize, tbl_perplexity)

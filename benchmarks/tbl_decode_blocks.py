@@ -51,8 +51,8 @@ def _case(seq_len: int):
     H = HKV * G
     ks = jax.random.split(jax.random.PRNGKey(7), 3)
     q = jax.random.normal(ks[0], (B, H, D))
-    kp = jax.random.normal(ks[1], (B * mp, PAGE_SIZE, HKV, D))
-    vp = jax.random.normal(ks[2], (B * mp, PAGE_SIZE, HKV, D))
+    kp = jax.random.normal(ks[1], (B * mp, HKV, PAGE_SIZE, D))
+    vp = jax.random.normal(ks[2], (B * mp, HKV, PAGE_SIZE, D))
     bt = jnp.arange(B * mp, dtype=jnp.int32).reshape(B, mp)
     lens = jnp.asarray([seq_len, seq_len - 3 * PAGE_SIZE - 5], jnp.int32)
     return q, kp, vp, bt, lens, mp
@@ -94,7 +94,7 @@ def run(fast: bool = False, backend: Optional[str] = None):
                 fn = jax.jit(
                     lambda q, kp, vp, bt, l, ppb=ppb, ns=ns, cm=cm, be=be:
                     decode_attention(q, kp, vp, bt, l, impl="pallas",
-                                     interpret=True, pages_per_block=ppb,
+                                     pages_per_block=ppb,
                                      num_splits=ns, combine_mode=cm,
                                      backend=be))
                 uss[cm] = timeit(fn, q, kp, vp, bt, lens,

@@ -6,13 +6,19 @@ contiguous-baseline engine under the SAME byte budget. Prints throughput,
 TTFT percentiles, preemption counts, and the memory ledger.
 
 Run:  PYTHONPATH=src python examples/serve_batch.py [--arch granite-8b]
+
+Off a TPU it serves the reduced smoke config (kernels interpreted).  On a
+TPU, ``--full --layers N --dtype bfloat16`` serves the published widths
+with the depth cut to N layers and the kernels compiled.
 """
 
 import argparse
 import time
 
+import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.serving import Engine, Request
 
@@ -29,8 +35,15 @@ def main():
     ap.add_argument("--arch", default="llama2-7b")
     ap.add_argument("--requests", type=int, default=12)
     ap.add_argument("--max-new", type=int, default=8)
-    ap.add_argument("--impl", default="ref", choices=["ref", "pallas"],
-                    help="decode attention op: jnp oracle or Pallas kernel")
+    ap.add_argument("--impl", default="pallas", choices=["ref", "pallas"],
+                    help="attention ops: Pallas kernels or the jnp oracle")
+    ap.add_argument("--full", action="store_true",
+                    help="published widths instead of the smoke config")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the depth to this many layers")
+    ap.add_argument("--dtype", default="float32",
+                    choices=["float32", "bfloat16"],
+                    help="weights, activations and KV pages")
     ap.add_argument("--pages-per-block", type=int, default=None,
                     help="Pallas kernel KV-block width (default: auto)")
     ap.add_argument("--num-splits", type=int, default=None,
@@ -57,7 +70,13 @@ def main():
                          "only their own tail")
     args = ap.parse_args()
 
-    cfg = get_config(args.arch).smoke()
+    enable_compile_cache()
+    cfg = get_config(args.arch)
+    if not args.full:
+        cfg = cfg.smoke()
+    if args.layers is not None:
+        cfg = cfg.replace(n_layers=args.layers)
+    dtype = jnp.dtype(args.dtype)
     slots, max_seq, pool = 8, 128, 640
     rng = np.random.default_rng(0)
 
@@ -66,7 +85,7 @@ def main():
     print(f"== paged engine: {slots} slots, pool {pool} tokens, "
           f"impl={args.impl}, prefill={chunk} ==")
     eng = Engine(cfg, max_slots=slots, max_seq_len=max_seq,
-                 pool_tokens=pool, impl=args.impl,
+                 pool_tokens=pool, impl=args.impl, dtype=dtype,
                  pages_per_block=args.pages_per_block,
                  num_splits=args.num_splits,
                  combine_mode=args.combine_mode,
@@ -101,7 +120,7 @@ def main():
     slots_c = max(1, pool // max_seq)
     print(f"\n== contiguous baseline: {slots_c} slots (same bytes) ==")
     eng2 = Engine(cfg, params=eng.params, paged=False, max_slots=slots_c,
-                  max_seq_len=max_seq)
+                  max_seq_len=max_seq, dtype=dtype)
     reqs2 = wave(np.random.default_rng(0), args.requests,
                  max_seq - args.max_new, args.max_new)
     t0 = time.perf_counter()

@@ -362,6 +362,9 @@ class _Evaluator:
             node.args[1] if len(node.args) > 1 else None)
         block = self.eval(block_node, env) if block_node is not None else None
         index_map = self.eval(map_node, env) if map_node is not None else None
+        if isinstance(block, tuple):
+            # a None entry is a squeezed unit dim: block size 1
+            block = tuple(1 if d is None else d for d in block)
         if block is not None and not (
                 isinstance(block, tuple)
                 and all(isinstance(d, int) for d in block)):

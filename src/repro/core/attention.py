@@ -20,6 +20,7 @@ All functions are GQA-aware and sharding-agnostic (they may run inside
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -29,7 +30,7 @@ import numpy as np
 from repro.core import flex
 from repro.kernels.flex_attention.ops import flex_attention
 from repro.kernels.paged_attention.ops import paged_attention, paged_prefill
-from repro.kernels.paged_attention.ref import ring_slot_positions
+from repro.kernels.paged_attention.ref import gather_pages, ring_slot_positions
 
 # re-export: serving/bench code sizes decode grids through this module
 from repro.kernels.paged_attention.ops import choose_decode_params  # noqa: F401
@@ -59,6 +60,26 @@ def prefill_attention(
             return ring_attention(q, k, v, lens=lens, causal=causal,
                                   window=window, softcap=softcap)
         impl = "chunked"  # no mesh / indivisible seq: local fallback
+    if impl == "pallas":
+        return _flex_prefill(q, k, v, lens, window=window, softcap=softcap,
+                             causal=causal, interpret=interpret)
+    mask_mod, score_mod = _prefill_mods(lens, window, softcap, causal)
+    qt = q.transpose(0, 2, 1, 3)
+    kt = k.transpose(0, 2, 1, 3)
+    vt = v.transpose(0, 2, 1, 3)
+    if impl == "chunked":
+        # flash-style two-level chunking: O(q_chunk·kv_chunk) live scores.
+        # This is the path the multi-pod dry-run lowers for long sequences
+        # (the dense path would claim O(S²) temp bytes at 32k).
+        out = _chunked_attention(qt, kt, vt, mask_mod, score_mod)
+    else:
+        # jnp path: identical math, O(S²) scores — fine for smoke tests
+        out = _dense_attention(qt, kt, vt, mask_mod, score_mod)
+    return out.transpose(0, 2, 1, 3)
+
+
+def _prefill_mods(lens, window: int, softcap: float, causal: bool):
+    """(mask_mod, score_mod) of a prefill: causal or windowed, padded."""
     mods = []
     if causal:
         mods.append(flex.sliding_window_mask(window) if window > 0
@@ -69,22 +90,21 @@ def prefill_attention(
         mods.append(flex.padding_mask(lens))
     mask_mod = flex.and_masks(*mods) if mods else flex.full_mask
     score_mod = flex.softcap_score(softcap) if softcap > 0 else None
+    return mask_mod, score_mod
 
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    if impl == "pallas":
-        out = flex_attention(qt, kt, vt, mask_mod=mask_mod,
-                             score_mod=score_mod, window=window,
-                             interpret=interpret)
-    elif impl == "chunked":
-        # flash-style two-level chunking: O(q_chunk·kv_chunk) live scores.
-        # This is the path the multi-pod dry-run lowers for long sequences
-        # (the dense path would claim O(S²) temp bytes at 32k).
-        out = _chunked_attention(qt, kt, vt, mask_mod, score_mod)
-    else:
-        # jnp path: identical math, O(S²) scores — fine for smoke tests
-        out = _dense_attention(qt, kt, vt, mask_mod, score_mod)
+
+@functools.partial(jax.jit, static_argnames=("window", "softcap", "causal",
+                                             "interpret"))
+def _flex_prefill(q, k, v, lens, *, window: int, softcap: float,
+                  causal: bool, interpret: Optional[bool]):
+    """The flex kernel under one jit: the mods are closures made per
+    call, so an eager kernel launch would trace and compile anew for
+    every layer of every prefill; here it compiles once per shape."""
+    mask_mod, score_mod = _prefill_mods(lens, window, softcap, causal)
+    out = flex_attention(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                         v.transpose(0, 2, 1, 3), mask_mod=mask_mod,
+                         score_mod=score_mod, window=window,
+                         interpret=interpret)
     return out.transpose(0, 2, 1, 3)
 
 
@@ -181,7 +201,7 @@ def _chunked_attention(q, k, v, mask_mod, score_mod,
 
 def prefill_attention_paged(
     q: jax.Array,  # (B, C, H, D) — one prompt *chunk* per sequence
-    k_pages: jax.Array,  # (num_pages, P, Hkv, D)
+    k_pages: jax.Array,  # (num_pages, Hkv, P, D)
     v_pages: jax.Array,
     block_tables: jax.Array,  # (B, max_pages)
     kv_lens: jax.Array,  # (B,) cached tokens incl. the chunk
@@ -217,7 +237,7 @@ def prefill_attention_windowed_chunk(
     q: jax.Array,  # (B, C, H, D)
     k_new: jax.Array,  # (B, C, Hkv, D) — the chunk's fresh K/V
     v_new: jax.Array,
-    k_pages: jax.Array,  # (num_pages, P, Hkv, D) — ring pools, pre-write
+    k_pages: jax.Array,  # (num_pages, Hkv, P, D) — ring pools, pre-write
     v_pages: jax.Array,
     block_tables: jax.Array,  # (B, ring)
     q_start: jax.Array,  # (B,) cached prefix length (chunk NOT yet written)
@@ -237,7 +257,7 @@ def prefill_attention_windowed_chunk(
     chunk into the ring afterwards.  Bounded working set — the ring is
     small by construction, so a jnp path suffices."""
     B, C, H, D = q.shape
-    num_pages, P, Hkv, _ = k_pages.shape
+    num_pages, Hkv, P, _ = k_pages.shape
     G = H // Hkv
     scale = 1.0 / np.sqrt(D)
 
@@ -248,10 +268,8 @@ def prefill_attention_windowed_chunk(
     S = block_tables.shape[1] * P
 
     safe = jnp.clip(block_tables, 0, num_pages - 1)
-    kpre = jax.lax.optimization_barrier(
-        k_pages[safe].reshape(B, S, Hkv, D))
-    vpre = jax.lax.optimization_barrier(
-        v_pages[safe].reshape(B, S, Hkv, D))
+    kpre = jax.lax.optimization_barrier(gather_pages(k_pages, safe))
+    vpre = jax.lax.optimization_barrier(gather_pages(v_pages, safe))
     if kv_scale > 0:
         kpre = (kpre.astype(jnp.float32) * kv_scale).astype(q.dtype)
         vpre = (vpre.astype(jnp.float32) * kv_scale).astype(q.dtype)
@@ -287,7 +305,7 @@ def prefill_attention_windowed_chunk(
 
 def decode_attention(
     q: jax.Array,  # (B, H, D) — one token per sequence
-    k_pages: jax.Array,  # (num_pages, P, Hkv, D)
+    k_pages: jax.Array,  # (num_pages, Hkv, P, D)
     v_pages: jax.Array,
     block_tables: jax.Array,  # (B, max_pages)
     lens: jax.Array,  # (B,)
@@ -357,7 +375,7 @@ def _partial_decode(q, k_pages, v_pages, block_tables, lens, *, window=0,
     table slot j covers logical page j·page_stride + page_offset.
     """
     B, H, D = q.shape
-    num_pages, P, Hkv, _ = k_pages.shape
+    num_pages, Hkv, P, _ = k_pages.shape
     max_pages = block_tables.shape[1]
     S = max_pages * P
     scale = 1.0 / np.sqrt(D)
@@ -367,8 +385,8 @@ def _partial_decode(q, k_pages, v_pages, block_tables, lens, *, window=0,
     # gathered page-working-set instead of being hoisted onto the whole pool
     # (the CPU backend's float-normalization pass would otherwise shadow the
     # full pool in f32 — pool-sized dead memory; harmless no-op on TPU).
-    k = jax.lax.optimization_barrier(k_pages[safe].reshape(B, S, Hkv, D))
-    v = jax.lax.optimization_barrier(v_pages[safe].reshape(B, S, Hkv, D))
+    k = jax.lax.optimization_barrier(gather_pages(k_pages, safe))
+    v = jax.lax.optimization_barrier(gather_pages(v_pages, safe))
     if kv_scale > 0:  # int8 pools: dequantize the gathered working set
         k = (k.astype(jnp.float32) * kv_scale).astype(q.dtype)
         v = (v.astype(jnp.float32) * kv_scale).astype(q.dtype)

@@ -1,6 +1,8 @@
 """Paged KV cache: page pools + block tables, shared across layers.
 
-Pools are shaped (n_layers, num_pages, page_size, kv_heads, head_dim).
+Pools are shaped (n_layers, num_pages, kv_heads, page_size, head_dim):
+head-major within a page, so each (page, kv head) pair is one contiguous
+(page_size, head_dim) tile — the block the Pallas kernels stream.
 All sequences of a batch share one pool (the paper's *global KV cache*);
 the same block table row addresses every layer's pool (standard paged-KV
 layout — one indirection, L pools).
@@ -26,16 +28,17 @@ import jax.numpy as jnp
 
 from repro.core import paging
 from repro.core.paging import PageState
+from repro.kernels.paged_attention.ref import gather_pages
 
 
 class PagedKVCache(NamedTuple):
-    k_pages: jax.Array  # (L, num_pages, page_size, kv_heads, head_dim)
-    v_pages: jax.Array  # (L, num_pages, page_size, kv_heads, head_dim)
+    k_pages: jax.Array  # (L, num_pages, kv_heads, page_size, head_dim)
+    v_pages: jax.Array  # (L, num_pages, kv_heads, page_size, head_dim)
     state: PageState
 
     @property
     def page_size(self) -> int:
-        return self.k_pages.shape[2]
+        return self.k_pages.shape[3]
 
     @property
     def num_pages(self) -> int:
@@ -45,7 +48,7 @@ class PagedKVCache(NamedTuple):
 def init_cache(n_layers: int, num_pages: int, page_size: int, kv_heads: int,
                head_dim: int, max_seqs: int, max_pages_per_seq: int,
                dtype=jnp.float32) -> PagedKVCache:
-    shape = (n_layers, num_pages, page_size, kv_heads, head_dim)
+    shape = (n_layers, num_pages, kv_heads, page_size, head_dim)
     return PagedKVCache(
         k_pages=jnp.zeros(shape, dtype),
         v_pages=jnp.zeros(shape, dtype),
@@ -55,13 +58,15 @@ def init_cache(n_layers: int, num_pages: int, page_size: int, kv_heads: int,
 
 def _scatter_tokens(pages: jax.Array, phys_pages: jax.Array, offsets: jax.Array,
                     vals: jax.Array) -> jax.Array:
-    """pages: (num_pages, P, H, D); phys/offsets: (...,); vals: (..., H, D)."""
+    """pages: (num_pages, H, P, D); phys/offsets: (...,); vals: (..., H, D)."""
     flat_pages = phys_pages.reshape(-1)
     flat_off = offsets.reshape(-1)
     flat_vals = vals.reshape(-1, *vals.shape[-2:])
     # drop writes through NULL pages (unallocated → scheduler bug upstream)
     oob = jnp.where(flat_pages < 0, pages.shape[0], flat_pages)
-    return pages.at[oob, flat_off].set(flat_vals, mode="drop")
+    # the two index arrays straddle the head slice, so the updated
+    # elements are laid out (N, H, D) — the shape of flat_vals
+    return pages.at[oob, :, flat_off].set(flat_vals, mode="drop")
 
 
 def write_decode(cache: PagedKVCache, layer: int, seq_ids: jax.Array,
@@ -93,7 +98,7 @@ def write_layer_decode(k_pages_l: jax.Array, v_pages_l: jax.Array,
                        v_new: jax.Array, window: int = 0
                        ) -> Tuple[jax.Array, jax.Array]:
     """Per-layer variant for use inside the layer scan (pools as scan xs)."""
-    ps = k_pages_l.shape[1]
+    ps = k_pages_l.shape[2]
     logical = positions // ps
     if window > 0:
         ring = -(-window // ps) + 1
@@ -114,7 +119,7 @@ def write_layer_prefill(k_pages_l: jax.Array, v_pages_l: jax.Array,
     0..S-1 per sequence; tokens past ``lens`` are masked out.
     """
     B, S = k.shape[:2]
-    ps = k_pages_l.shape[1]
+    ps = k_pages_l.shape[2]
     pos = jnp.arange(S, dtype=jnp.int32)[None, :].repeat(B, 0)
     logical = pos // ps
     valid = pos < lens[:, None]
@@ -147,7 +152,7 @@ def write_layer_prefill_at(k_pages_l: jax.Array, v_pages_l: jax.Array,
     each (page, offset) slot (deterministic scatter).
     """
     B, C = k.shape[:2]
-    ps = k_pages_l.shape[1]
+    ps = k_pages_l.shape[2]
     off_i = jnp.arange(C, dtype=jnp.int32)[None, :]
     pos = start[:, None].astype(jnp.int32) + off_i
     logical = pos // ps
@@ -174,15 +179,13 @@ def gather_layer(k_pages_l: jax.Array, v_pages_l: jax.Array,
     Reference path only — the Pallas kernel consumes pages without this copy.
     ``tables``: (B, max_pages).
     """
-    ps = k_pages_l.shape[1]
+    ps = k_pages_l.shape[2]
     n_pages = -(-max_len // ps)
     tables = tables[:, :n_pages]  # (B, n_pages)
     safe = jnp.clip(tables, 0, k_pages_l.shape[0] - 1)
-    k = k_pages_l[safe]  # (B, n_pages, ps, H, D)
-    v = v_pages_l[safe]
-    mask = (tables >= 0)[:, :, None, None, None]
-    k = jnp.where(mask, k, 0).reshape(k.shape[0], n_pages * ps, *k.shape[-2:])
-    v = jnp.where(mask, v, 0).reshape(v.shape[0], n_pages * ps, *v.shape[-2:])
+    live = jnp.repeat(tables >= 0, ps, axis=1)[:, :, None, None]
+    k = jnp.where(live, gather_pages(k_pages_l, safe), 0)
+    v = jnp.where(live, gather_pages(v_pages_l, safe), 0)
     return k[:, :max_len], v[:, :max_len]
 
 

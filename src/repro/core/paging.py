@@ -9,7 +9,7 @@ decode critical path).  A host-side mirror (`HostPageManager`) gives the
 serving scheduler true O(1) integer ops for admission control.
 
 Page-pool layout contract (see DESIGN.md §4):
-  * physical pages live in pools shaped (num_pages, page_size, kv_heads, hd);
+  * physical pages live in pools shaped (num_pages, kv_heads, page_size, hd);
   * under the `tp` decode scheme the page dim is sharded over ("pod","data")
     — each data shard owns a private sub-pool and its slice of the batch;
   * under the `kvp` scheme the page dim is additionally sharded over

@@ -35,14 +35,12 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-try:
-    from jax import shard_map as _shard_map  # jax >= 0.8 (check_vma kwarg)
+from jax import shard_map as _shard_map
 
-    def shard_map(f, mesh, in_specs, out_specs, check_rep=False):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check_rep)
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map
+
+def shard_map(f, mesh, in_specs, out_specs, check_rep=False):
+    return _shard_map(f, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=check_rep)
 
 from repro.core import attention as core_attn
 from repro.core import cache as kvcache
@@ -52,8 +50,8 @@ from repro.distributed.sharding import axis_size, current_mesh
 def _flat_axis_index(axes: Tuple[str, ...]) -> jax.Array:
     idx = jnp.int32(0)
     for a in axes:
-        # axis_size resolves statically from the mesh context (this jax
-        # has no jax.lax.axis_size); axis_index is per-shard as usual
+        # axis_size resolves statically from the mesh context;
+        # axis_index is per-shard as usual
         idx = idx * axis_size(a) + jax.lax.axis_index(a)
     return idx
 
@@ -115,7 +113,7 @@ def merge_flash_partials(
 
 def decode_attention_sharded(
     q4: jax.Array,  # (B, Hkv, G, hd) — q heads grouped by kv head
-    k_pages: jax.Array,  # (num_pages, P, Hkv, hd)
+    k_pages: jax.Array,  # (num_pages, Hkv, P, hd)
     v_pages: jax.Array,
     tables: jax.Array,  # (B, n_kv_shards, pages_per_shard) int32
     lens: jax.Array,  # (B,)
@@ -155,7 +153,7 @@ def decode_attention_sharded(
 
     if scheme == "tp":
         in_specs = (P(ba, "model", None, None),
-                    P(ba, None, "model", None), P(ba, None, "model", None),
+                    P(ba, "model", None, None), P(ba, "model", None, None),
                     P(ba, None, None), P(ba))
         fn = shard_map(_local, mesh=mesh, in_specs=in_specs,
                        out_specs=P(ba, "model", None, None), check_rep=False)
@@ -193,7 +191,7 @@ def decode_attention_sharded(
 
 
 def write_prefill_sharded(
-    k_pages_l: jax.Array,  # (num_pages, P, Hkv, hd)
+    k_pages_l: jax.Array,  # (num_pages, Hkv, P, hd)
     v_pages_l: jax.Array,
     tables: jax.Array,  # (B, max_pages) — pool-shard-local physical ids
     k: jax.Array,  # (B, S, Hkv, hd)
@@ -240,7 +238,7 @@ def write_prefill_sharded(
 
 
 def write_decode_sharded(
-    k_pages: jax.Array,  # (num_pages, P, Hkv, hd)
+    k_pages: jax.Array,  # (num_pages, Hkv, P, hd)
     v_pages: jax.Array,
     tables: jax.Array,  # (B, n_kv_shards, pages_per_shard)
     positions: jax.Array,  # (B,) — 0-based position of the incoming token
@@ -253,12 +251,12 @@ def write_decode_sharded(
 ) -> Tuple[jax.Array, jax.Array]:
     """Scatter one new token per sequence into the (sharded) pools."""
     mesh = current_mesh()
-    page_size = k_pages.shape[1]
+    page_size = k_pages.shape[2]
 
     def _scatter(kp, vp, phys, off, k, v):
         oob = jnp.where(phys < 0, kp.shape[0], phys)
-        return (kp.at[oob, off].set(k, mode="drop"),
-                vp.at[oob, off].set(v, mode="drop"))
+        return (kp.at[oob, :, off].set(k, mode="drop"),
+                vp.at[oob, :, off].set(v, mode="drop"))
 
     def _local(kp, vp, tbl, pos, k, v, stride=1, offset=0):
         logical = pos // page_size
@@ -282,10 +280,10 @@ def write_decode_sharded(
     ba = tuple(batch_axes) or None
 
     if scheme == "tp":
-        in_specs = (P(ba, None, "model", None), P(ba, None, "model", None),
+        in_specs = (P(ba, "model", None, None), P(ba, "model", None, None),
                     P(ba, None, None), P(ba),
                     P(ba, "model", None), P(ba, "model", None))
-        out_specs = (P(ba, None, "model", None), P(ba, None, "model", None))
+        out_specs = (P(ba, "model", None, None), P(ba, "model", None, None))
         fn = shard_map(_local, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_rep=False)
         return fn(k_pages, v_pages, tables, positions, k_new, v_new)

@@ -22,7 +22,6 @@ from typing import Any, Mapping, Optional, Sequence, Tuple, Union
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.errors import DistributedSetupError
 
 PhysAxes = Union[None, str, Tuple[str, ...]]
 
@@ -121,20 +120,14 @@ def _mesh_axis_sizes(mesh: Mesh) -> Mapping[str, int]:
 def axis_size(name: str) -> int:
     """Static size of a named mesh axis, usable inside shard_map bodies.
 
-    ``jax.lax.axis_size`` only exists in newer jax; callers here need a
-    *static* int anyway (ring permutation lists, mixed-radix index math),
-    so resolve from the active mesh context first and fall back to the
-    jax primitive when available.
+    Callers need a *static* int (ring permutation lists, mixed-radix index
+    math), so resolve from the active mesh context first and otherwise
+    ask ``jax.lax.axis_size``.
     """
     mesh = current_mesh()
     if mesh is not None and name in mesh.axis_names:
         return _mesh_axis_sizes(mesh)[name]
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(name)
-    raise DistributedSetupError(
-        f"axis_size({name!r}): no active mesh defines it and this jax has "
-        "no jax.lax.axis_size", axis=name)
+    return jax.lax.axis_size(name)
 
 
 def logical_spec(

@@ -13,7 +13,8 @@ Backend-selection contract: every kernel-facing op takes
 falling back to the TPU lowering on CPU hosts) and ``interpret=None``
 (auto: interpret mode unless the process runs on the backend the kernel
 targets — so CPU CI exercises both lowerings through the Pallas
-interpreter while real TPUs/GPUs compile).
+interpreter while real TPUs/GPUs compile).  On a TPU an interpreted
+kernel is an error, never a silent fallback.
 """
 
 from __future__ import annotations
@@ -55,7 +56,13 @@ def resolve_backend(backend: Optional[str]) -> str:
 def resolve_interpret(interpret: Optional[bool],
                       backend: str = "tpu") -> bool:
     """``None`` → auto (interpret iff not running on ``backend``'s
-    platform); bools pass through."""
-    if interpret is not None:
-        return bool(interpret)
-    return not _on_platform(backend)
+    platform); bools pass through.  On a TPU host an interpreted kernel
+    is refused: the interpreter there would hide the device path."""
+    if interpret is None:
+        interpret = not _on_platform(backend)
+    if interpret and _on_platform("tpu"):
+        raise EngineConfigError(
+            f"Pallas kernel for backend {backend!r} would run in interpret "
+            "mode on a TPU; use the TPU lowering compiled (interpret=None)",
+            backend=backend)
+    return bool(interpret)

@@ -35,6 +35,26 @@ from repro.kernels import resolve_interpret
 NEG_INF = -1e30
 
 
+class _AuxView:
+    """A scalar-prefetch aux operand as the mask/score mods see it.
+
+    A lookup whose indices are all scalars (``lens[b]``, ``slopes[h]``)
+    reads one SMEM scalar: Mosaic cannot load a whole scalar-prefetch
+    array into vector registers.  A lookup with vector indices
+    (``docs[b, q]``) loads the array first; Mosaic has no vector gather
+    from SMEM, so such mods run only under the interpreter.
+    """
+
+    def __init__(self, ref):
+        self.ref = ref
+
+    def __getitem__(self, idx):
+        idx = idx if isinstance(idx, tuple) else (idx,)
+        if all(jnp.ndim(i) == 0 for i in idx):
+            return self.ref[idx]
+        return self.ref[...][idx]
+
+
 def _flex_kernel(
     # scalar prefetch: block mask + aux tensors (FlexAttention "bias" trick)
     kv_num_blocks_ref,  # (nq,)
@@ -51,11 +71,11 @@ def _flex_kernel(
     q_len: int,
     kv_len: int,
 ):
-    aux_refs = refs[: n_mask_aux + n_score_aux]
+    aux_refs = tuple(_AuxView(r) for r in refs[: n_mask_aux + n_score_aux])
     q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs[
         n_mask_aux + n_score_aux:]
-    mask_aux = tuple(r[...] for r in aux_refs[:n_mask_aux])
-    score_aux = tuple(r[...] for r in aux_refs[n_mask_aux:])
+    mask_aux = aux_refs[:n_mask_aux]
+    score_aux = aux_refs[n_mask_aux:]
 
     def mask_mod(b, h, q, k):
         return mask_fn(b, h, q, k, *mask_aux)
@@ -99,7 +119,8 @@ def _flex_kernel(
         ki = kb * kv_blk + jax.lax.broadcasted_iota(jnp.int32, (q_blk, kv_blk), 1)
         if score_mod is not None:
             s = score_mod(s, b, h, qi, ki)
-        mask = jnp.where(full, jnp.ones_like(s, bool), mask_mod(b, h, qi, ki))
+        # a boolean `where` does not legalize in Mosaic; `|` does
+        mask = full | mask_mod(b, h, qi, ki)
         mask &= (qi < q_len) & (ki < kv_len)  # block-padding validity
         s = jnp.where(mask, s, NEG_INF)
 

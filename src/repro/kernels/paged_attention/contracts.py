@@ -128,7 +128,7 @@ def _prefill_samples() -> List[Dict]:
 
 def _kv_pool(n_kv: str = "n_kv") -> Dict:
     return {"name": "k_pages",
-            "shape": ("num_pages", "page_size", n_kv, "D"),
+            "shape": ("num_pages", n_kv, "page_size", "D"),
             "dtype": "float32"}
 
 
@@ -136,13 +136,13 @@ def _kv_pool(n_kv: str = "n_kv") -> Dict:
 # the contract table — one entry per pallas_call site in src/repro/kernels/
 # ---------------------------------------------------------------------------
 _DECODE_OUTPUTS = [
-    {"shape": ("B", "n_kv", "S", "G"), "dtype": "float32"},        # m
-    {"shape": ("B", "n_kv", "S", "G"), "dtype": "float32"},        # l
+    {"shape": ("B", "n_kv", "S", "G", 1), "dtype": "float32"},     # m
+    {"shape": ("B", "n_kv", "S", "G", 1), "dtype": "float32"},     # l
     {"shape": ("B", "n_kv", "S", "G", "D"), "dtype": "float32"},   # acc
 ]
 _PREFILL_OUTPUTS = [
-    {"shape": ("B", "n_kv", "NQ", "S", "R"), "dtype": "float32"},
-    {"shape": ("B", "n_kv", "NQ", "S", "R"), "dtype": "float32"},
+    {"shape": ("B", "n_kv", "NQ", "S", "R", 1), "dtype": "float32"},
+    {"shape": ("B", "n_kv", "NQ", "S", "R", 1), "dtype": "float32"},
     {"shape": ("B", "n_kv", "NQ", "S", "R", "D"), "dtype": "float32"},
 ]
 _TABLES3D = {"name": "tables3d", "shape": ("B", "NB", "ppb"),
@@ -225,9 +225,9 @@ CONTRACTS: Dict[str, Dict] = {
         "grid": ("B", "Hkv"),
         "num_scalar_prefetch": 0,
         "operands": [
-            {"name": "m", "shape": ("B", "Hkv", "S", "G"),
+            {"name": "m", "shape": ("B", "Hkv", "S", "G", 1),
              "dtype": "float32"},
-            {"name": "l", "shape": ("B", "Hkv", "S", "G"),
+            {"name": "l", "shape": ("B", "Hkv", "S", "G", 1),
              "dtype": "float32"},
             {"name": "acc", "shape": ("B", "Hkv", "S", "G", "D"),
              "dtype": "float32"},

@@ -14,7 +14,7 @@ CPU hosts fall back to the TPU lowering in interpret mode):
     scattered pages HBM→VMEM, double-buffered; megacore
     ``dimension_semantics`` parallelise (batch, kv_head, split).
   * ``"gpu"`` — `paged_attention_gpu.py`: the Triton lowering
-    (``plgpu.TritonCompilerParams``) gathers pages *inside* the kernel
+    (``plgpu.CompilerParams``) gathers pages *inside* the kernel
     with block-table indexed ``tl.load``s, one CTA per (batch, kv_head,
     split) grid slot.
 
@@ -179,7 +179,7 @@ def choose_prefill_params(
 )
 def paged_prefill(
     q: jax.Array,  # (B, C, n_heads, head_dim) — one prompt chunk per seq
-    k_pages: jax.Array,  # (num_pages, page_size, n_kv_heads, head_dim)
+    k_pages: jax.Array,  # (num_pages, n_kv_heads, page_size, head_dim)
     v_pages: jax.Array,
     block_tables: jax.Array,  # (B, max_pages)
     kv_lens: jax.Array,  # (B,) cached tokens incl. the chunk
@@ -203,8 +203,8 @@ def paged_prefill(
     `paged_attention`; see `ref.paged_prefill_ref` for the contract.
     """
     B, C, n_heads, head_dim = q.shape
-    n_kv = k_pages.shape[2]
-    page_size = k_pages.shape[1]
+    n_kv = k_pages.shape[1]
+    page_size = k_pages.shape[2]
     max_pages = block_tables.shape[1]
     scale = float(scale if scale is not None else 1.0 / np.sqrt(head_dim))
 
@@ -234,7 +234,7 @@ def paged_prefill(
 )
 def paged_attention(
     q: jax.Array,  # (B, n_heads, head_dim)
-    k_pages: jax.Array,  # (num_pages, page_size, n_kv_heads, head_dim)
+    k_pages: jax.Array,  # (num_pages, n_kv_heads, page_size, head_dim)
     v_pages: jax.Array,
     block_tables: jax.Array,  # (B, max_pages)
     lens: jax.Array,  # (B,)
@@ -252,8 +252,8 @@ def paged_attention(
 ) -> jax.Array:
     """Attention of one query token per sequence over its paged KV cache."""
     B, n_heads, head_dim = q.shape
-    n_kv = k_pages.shape[2]
-    page_size = k_pages.shape[1]
+    n_kv = k_pages.shape[1]
+    page_size = k_pages.shape[2]
     max_pages = block_tables.shape[1]
     scale = float(scale if scale is not None else 1.0 / np.sqrt(head_dim))
 

@@ -161,18 +161,18 @@ def _combine_partials_jnp(m: jax.Array, l: jax.Array, acc: jax.Array,
 def _combine_kernel(m_ref, l_ref, acc_ref, o_ref):
     """Reduce the split axis of one (b, h) slot on-chip.
 
-    Blocks: m/l (1, 1, S, G), acc (1, 1, S, G, D), out (1, 1, G, D).
+    Blocks: m/l (1, 1, S, G, 1), acc (1, 1, S, G, D), out (1, 1, G, D).
     Max-shift merge in f32; an all-dead slot (every m == NEG_INF, l == 0)
     yields exact zeros via the ε-clamped denominator.
     """
-    m = m_ref[0, 0]  # (S, G) f32
+    m = m_ref[0, 0]  # (S, G, 1) f32
     l = l_ref[0, 0]
     acc = acc_ref[0, 0]  # (S, G, D) f32
-    m_g = jnp.max(m, axis=0, keepdims=True)  # (1, G)
-    corr = jnp.exp(m - m_g)  # (S, G)
-    l_g = jnp.sum(l * corr, axis=0)  # (G,)
-    o = jnp.sum(acc * corr[..., None], axis=0)  # (G, D)
-    o_ref[0, 0] = (o / jnp.maximum(l_g, 1e-30)[:, None]).astype(o_ref.dtype)
+    m_g = jnp.max(m, axis=0, keepdims=True)  # (1, G, 1)
+    corr = jnp.exp(m - m_g)  # (S, G, 1)
+    l_g = jnp.sum(l * corr, axis=0)  # (G, 1)
+    o = jnp.sum(acc * corr, axis=0)  # (G, D)
+    o_ref[0, 0] = (o / jnp.maximum(l_g, 1e-30)).astype(o_ref.dtype)
 
 
 def combine_partials_pallas(m: jax.Array, l: jax.Array, acc: jax.Array,
@@ -183,11 +183,15 @@ def combine_partials_pallas(m: jax.Array, l: jax.Array, acc: jax.Array,
     m, l: (B, Hkv, S, G); acc: (B, Hkv, S, G, D) — f32 (cast if not).
     Returns (B, Hkv, G, D) in ``dtype``.  Both grid axes are marked
     ``"parallel"`` — every (b, h) reduction is independent, so megacore
-    TPUs split the grid across cores.
+    TPUs split the grid across cores.  m and l enter the kernel with a
+    trailing unit axis, as the partial kernels emit them.
     """
     B, Hkv, S, G = m.shape
     D = acc.shape[-1]
-    part_spec = pl.BlockSpec((1, 1, S, G), lambda b, h: (b, h, 0, 0))
+    m = m.astype(jnp.float32)[..., None]
+    l = l.astype(jnp.float32)[..., None]
+    acc = acc.astype(jnp.float32)
+    part_spec = pl.BlockSpec((1, 1, S, G, 1), lambda b, h: (b, h, 0, 0, 0))
     return pl.pallas_call(
         _combine_kernel,
         grid=(B, Hkv),
@@ -198,10 +202,10 @@ def combine_partials_pallas(m: jax.Array, l: jax.Array, acc: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=COMBINE_DIM_SEMANTICS),
         interpret=resolve_interpret(interpret),
-    )(m.astype(jnp.float32), l.astype(jnp.float32), acc.astype(jnp.float32))
+    )(m, l, acc)
 
 
 def combine_partials(m: jax.Array, l: jax.Array, acc: jax.Array,
@@ -233,7 +237,7 @@ def _decode_kernel(
     # 3 scratch (see pallas_call below)
     ppb = pages_per_block
     tables_ref, lens_ref, q_ref = refs[0], refs[1], refs[2]
-    k_refs = refs[3:3 + ppb]  # each (1, P, 1, D)
+    k_refs = refs[3:3 + ppb]  # each (P, D): one (page, kv head) tile
     v_refs = refs[3 + ppb:3 + 2 * ppb]
     m_out, l_out, acc_out = refs[3 + 2 * ppb:6 + 2 * ppb]
     m_ref, l_ref, acc_ref = refs[6 + 2 * ppb:]
@@ -241,7 +245,7 @@ def _decode_kernel(
     b = pl.program_id(0)
     s = pl.program_id(2)
     blk = pl.program_id(3)
-    page_size = k_refs[0].shape[1]
+    page_size = k_refs[0].shape[0]
 
     @pl.when(blk == 0)
     def _init():
@@ -252,33 +256,30 @@ def _decode_kernel(
     L = lens_ref[b]
     block_rank = s * blocks_per_split + blk  # global KV-block index
     first_page = block_rank * ppb
-    slot = jax.lax.broadcasted_iota(jnp.int32, (page_size,), 0)
+    # one iota over the whole block: Mosaic cannot concatenate boolean
+    # vectors, so the per-token mask is computed in one piece
+    idx = jax.lax.broadcasted_iota(jnp.int32, (1, ppb * page_size), 1)
 
-    lives = []
     if window > 0:
         ring = -(-window // page_size) + 1
         cur_page = jnp.maximum(L - 1, 0) // page_size
-        for j in range(ppb):
-            pg = first_page + j
-            # ring slot → logical position (see ref.ring_slot_positions)
-            lpage = cur_page - ((cur_page - pg) % ring)
-            pos = lpage * page_size + slot
-            pos = jnp.where(pos >= L, pos - ring * page_size, pos)
-            lives.append((pos >= 0) & (pos < L) & (pos >= L - window)
-                         & (pg < ring))
+        pg = first_page + idx // page_size
+        # ring slot → logical position (see ref.ring_slot_positions)
+        lpage = cur_page - ((cur_page - pg) % ring)
+        pos = lpage * page_size + idx % page_size
+        pos = jnp.where(pos >= L, pos - ring * page_size, pos)
+        live = ((pos >= 0) & (pos < L) & (pos >= L - window)
+                & (pg < ring))  # (1, ppb·P)
         block_live = first_page < ring
     else:
-        for j in range(ppb):
-            pos = (first_page + j) * page_size + slot
-            lives.append(pos < L)
+        live = first_page * page_size + idx < L  # (1, ppb·P)
         block_live = first_page * page_size < L
-    live = jnp.concatenate(lives)  # (ppb·P,)
 
     @pl.when(block_live)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * scale  # (G, D)
-        k = jnp.concatenate([r[0, :, 0, :] for r in k_refs], axis=0)
-        v = jnp.concatenate([r[0, :, 0, :] for r in v_refs], axis=0)
+        k = jnp.concatenate([r[...] for r in k_refs], axis=0)
+        v = jnp.concatenate([r[...] for r in v_refs], axis=0)
         k = k.astype(jnp.float32)  # (ppb·P, D)
         v = v.astype(jnp.float32)
         if kv_scale > 0:  # int8 pages: dequantize the VMEM tile in-register
@@ -289,13 +290,13 @@ def _decode_kernel(
                                  preferred_element_type=jnp.float32)
         if softcap > 0:
             s_ = softcap * jnp.tanh(s_ / softcap)
-        s_ = jnp.where(live[None, :], s_, NEG_INF)  # (G, ppb·P)
+        s_ = jnp.where(live, s_, NEG_INF)  # (G, ppb·P)
 
         m_prev = m_ref[...]  # (G, 1)
         m_cur = jnp.max(s_, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         alpha = jnp.exp(m_prev - m_new)
-        pexp = jnp.where(live[None, :], jnp.exp(s_ - m_new), 0.0)
+        pexp = jnp.where(live, jnp.exp(s_ - m_new), 0.0)
 
         l_ref[...] = l_ref[...] * alpha + jnp.sum(pexp, axis=1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
@@ -305,8 +306,8 @@ def _decode_kernel(
 
     @pl.when(blk == blocks_per_split - 1)
     def _emit_partial():
-        m_out[0, 0, 0] = m_ref[...][:, 0]
-        l_out[0, 0, 0] = l_ref[...][:, 0]
+        m_out[0, 0, 0] = m_ref[...]
+        l_out[0, 0, 0] = l_ref[...]
         acc_out[0, 0, 0] = acc_ref[...]
 
 
@@ -335,7 +336,7 @@ def _blocked_tables(block_tables: jax.Array, lens: jax.Array, *,
 
 def paged_attention_kernel(
     q: jax.Array,  # (B, n_kv, G, D) — q heads grouped by kv head
-    k_pages: jax.Array,  # (num_pages, P, n_kv, D)
+    k_pages: jax.Array,  # (num_pages, n_kv, P, D)
     v_pages: jax.Array,
     block_tables: jax.Array,  # (B, max_pages) int32 (may contain -1)
     lens: jax.Array,  # (B,)
@@ -359,7 +360,7 @@ def paged_attention_kernel(
 
 def paged_attention_partials(
     q: jax.Array,  # (B, n_kv, G, D)
-    k_pages: jax.Array,  # (num_pages, P, n_kv, D)
+    k_pages: jax.Array,  # (num_pages, n_kv, P, D)
     v_pages: jax.Array,
     block_tables: jax.Array,  # (B, max_pages)
     lens: jax.Array,  # (B,)
@@ -372,9 +373,15 @@ def paged_attention_partials(
     pages_per_block: int = 1,
     num_splits: int = 1,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Split-K partials: ((B,n_kv,S,G) m, (B,n_kv,S,G) l, (B,n_kv,S,G,D) acc)."""
+    """Split-K partials: ((B,n_kv,S,G) m, (B,n_kv,S,G) l, (B,n_kv,S,G,D) acc).
+
+    The kernel emits m and l with a trailing unit axis, (B, n_kv, S, G, 1),
+    so that the last two dims of every output block equal the array's
+    (Mosaic's tiling rule for blocks smaller than (8, 128)); the unit axis
+    is squeezed here.
+    """
     B, n_kv, G, D = q.shape
-    num_pages, page_size, _, _ = k_pages.shape
+    num_pages, _, page_size, _ = k_pages.shape
     max_pages = block_tables.shape[1]
 
     ppb, _, S, bps = decode_partition(max_pages, pages_per_block, num_splits)
@@ -388,23 +395,21 @@ def paged_attention_partials(
         return (b, h, 0, 0)
 
     def part_map(b, h, s, blk, tables, lens):
-        return (b, h, s, 0)
-
-    def acc_map(b, h, s, blk, tables, lens):
         return (b, h, s, 0, 0)
 
     def kv_map(b, h, s, blk, tables, lens, *, j):
         del lens
-        return (tables[b, s * bps + blk, j], 0, h, 0)
+        return (tables[b, s * bps + blk, j], h, 0, 0)
 
-    kv_spec = lambda j: pl.BlockSpec((1, page_size, 1, D),
+    # one contiguous (page_size, D) tile per (page, kv head)
+    kv_spec = lambda j: pl.BlockSpec((None, None, page_size, D),
                                      functools.partial(kv_map, j=j))
 
     kernel = functools.partial(
         _decode_kernel, pages_per_block=ppb, blocks_per_split=bps,
         scale=scale, window=window, softcap=softcap, kv_scale=kv_scale)
 
-    return pl.pallas_call(
+    m, l, acc = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -415,9 +420,9 @@ def paged_attention_partials(
                 + [kv_spec(j) for j in range(ppb)]       # v pages of a block
             ),
             out_specs=[
-                pl.BlockSpec((1, 1, 1, G), part_map),
-                pl.BlockSpec((1, 1, 1, G), part_map),
-                pl.BlockSpec((1, 1, 1, G, D), acc_map),
+                pl.BlockSpec((1, 1, 1, G, 1), part_map),
+                pl.BlockSpec((1, 1, 1, G, 1), part_map),
+                pl.BlockSpec((1, 1, 1, G, D), part_map),
             ],
             scratch_shapes=[
                 pltpu.VMEM((G, 1), jnp.float32),
@@ -425,16 +430,17 @@ def paged_attention_partials(
                 pltpu.VMEM((G, D), jnp.float32),
             ],
         ),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=DECODE_DIM_SEMANTICS),
         out_shape=[
-            jax.ShapeDtypeStruct((B, n_kv, S, G), jnp.float32),
-            jax.ShapeDtypeStruct((B, n_kv, S, G), jnp.float32),
+            jax.ShapeDtypeStruct((B, n_kv, S, G, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, n_kv, S, G, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, n_kv, S, G, D), jnp.float32),
         ],
         interpret=resolve_interpret(interpret),
     )(tables3d, lens.astype(jnp.int32), q,
       *([k_pages] * ppb), *([v_pages] * ppb))
+    return m[..., 0], l[..., 0], acc
 
 
 def _prefill_kernel(
@@ -457,7 +463,7 @@ def _prefill_kernel(
     ppb = pages_per_block
     tables_ref, lens_ref, qstart_ref = refs[0], refs[1], refs[2]
     q_ref = refs[3]
-    k_refs = refs[4:4 + ppb]  # each (1, P, 1, D)
+    k_refs = refs[4:4 + ppb]  # each (P, D): one (page, kv head) tile
     v_refs = refs[4 + ppb:4 + 2 * ppb]
     m_out, l_out, acc_out = refs[4 + 2 * ppb:7 + 2 * ppb]
     m_ref, l_ref, acc_ref = refs[7 + 2 * ppb:]
@@ -466,7 +472,7 @@ def _prefill_kernel(
     nq = pl.program_id(2)
     s = pl.program_id(3)
     blk = pl.program_id(4)
-    page_size = k_refs[0].shape[1]
+    page_size = k_refs[0].shape[0]
     R = q_block * group  # rows: r = chunk-token·G + head-group
 
     @pl.when(blk == 0)
@@ -479,13 +485,10 @@ def _prefill_kernel(
     q0 = qstart_ref[b]  # absolute position of chunk token 0
     block_rank = s * blocks_per_split + blk
     first_page = block_rank * ppb
-    slot = jax.lax.broadcasted_iota(jnp.int32, (page_size,), 0)
-
-    kvpos = jnp.concatenate(
-        [(first_page + j) * page_size + slot for j in range(ppb)])
-    live_kv = kvpos < L  # (ppb·P,)
-    row = jax.lax.broadcasted_iota(jnp.int32, (R,), 0)
-    qpos = q0 + nq * q_block + row // group  # (R,) absolute q positions
+    kvpos = first_page * page_size + jax.lax.broadcasted_iota(
+        jnp.int32, (1, ppb * page_size), 1)  # (1, ppb·P)
+    row = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0)
+    qpos = q0 + nq * q_block + row // group  # (R, 1) absolute q positions
     # causal upper bound for the whole Q-block: KV blocks wholly past the
     # block's last query never contribute — skip their compute (their DMAs
     # are already elided by the rank clamp in `_blocked_tables`).
@@ -496,8 +499,8 @@ def _prefill_kernel(
     @pl.when(block_live)
     def _compute():
         q = q_ref[0, 0, 0].astype(jnp.float32) * scale  # (R, D)
-        k = jnp.concatenate([r[0, :, 0, :] for r in k_refs], axis=0)
-        v = jnp.concatenate([r[0, :, 0, :] for r in v_refs], axis=0)
+        k = jnp.concatenate([r[...] for r in k_refs], axis=0)
+        v = jnp.concatenate([r[...] for r in v_refs], axis=0)
         k = k.astype(jnp.float32)  # (ppb·P, D)
         v = v.astype(jnp.float32)
         if kv_scale > 0:
@@ -508,7 +511,7 @@ def _prefill_kernel(
                                  preferred_element_type=jnp.float32)
         if softcap > 0:
             s_ = softcap * jnp.tanh(s_ / softcap)
-        live = live_kv[None, :] & (kvpos[None, :] <= qpos[:, None])
+        live = (kvpos < L) & (kvpos <= qpos)
         s_ = jnp.where(live, s_, NEG_INF)  # (R, ppb·P)
 
         m_prev = m_ref[...]  # (R, 1)
@@ -525,8 +528,8 @@ def _prefill_kernel(
 
     @pl.when(blk == blocks_per_split - 1)
     def _emit_partial():
-        m_out[0, 0, 0, 0] = m_ref[...][:, 0]
-        l_out[0, 0, 0, 0] = l_ref[...][:, 0]
+        m_out[0, 0, 0, 0] = m_ref[...]
+        l_out[0, 0, 0, 0] = l_ref[...]
         acc_out[0, 0, 0, 0] = acc_ref[...]
 
 
@@ -571,7 +574,7 @@ def combine_prefill_partials(m: jax.Array, l: jax.Array, acc: jax.Array,
 
 def paged_prefill_partials(
     q: jax.Array,  # (B, C, n_heads, D) — one prompt chunk per sequence
-    k_pages: jax.Array,  # (num_pages, P, n_kv, D)
+    k_pages: jax.Array,  # (num_pages, n_kv, P, D)
     v_pages: jax.Array,
     block_tables: jax.Array,  # (B, max_pages) int32 (may contain -1)
     kv_lens: jax.Array,  # (B,) cached tokens incl. the chunk
@@ -591,10 +594,11 @@ def paged_prefill_partials(
     sharing `decode_partition`'s page ranges and the decode kernel's
     ``(m, l, acc)`` partial contract with the GQA row axis widened to
     ``q_block·G`` rows.  Returns ((B,Hkv,NQ,S,R) m, (B,Hkv,NQ,S,R) l,
-    (B,Hkv,NQ,S,R,D) acc) — f32, shaped for `combine_prefill_partials`.
+    (B,Hkv,NQ,S,R,D) acc) — f32, shaped for `combine_prefill_partials`
+    (m and l leave the kernel with a trailing unit axis, as in decode).
     """
     B, C, n_heads, D = q.shape
-    num_pages, page_size, n_kv, _ = k_pages.shape
+    num_pages, n_kv, page_size, _ = k_pages.shape
     max_pages = block_tables.shape[1]
     G = n_heads // n_kv
 
@@ -611,16 +615,13 @@ def paged_prefill_partials(
         return (b, h, nq, 0, 0)
 
     def part_map(b, h, nq, s, blk, tables, lens, qstart):
-        return (b, h, nq, s, 0)
-
-    def acc_map(b, h, nq, s, blk, tables, lens, qstart):
         return (b, h, nq, s, 0, 0)
 
     def kv_map(b, h, nq, s, blk, tables, lens, qstart, *, j):
         del lens, qstart
-        return (tables[b, s * bps + blk, j], 0, h, 0)
+        return (tables[b, s * bps + blk, j], h, 0, 0)
 
-    kv_spec = lambda j: pl.BlockSpec((1, page_size, 1, D),
+    kv_spec = lambda j: pl.BlockSpec((None, None, page_size, D),
                                      functools.partial(kv_map, j=j))
 
     kernel = functools.partial(
@@ -628,7 +629,7 @@ def paged_prefill_partials(
         q_block=q_block, group=G, scale=scale, softcap=softcap,
         kv_scale=kv_scale)
 
-    return pl.pallas_call(
+    m, l, acc = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
@@ -639,9 +640,9 @@ def paged_prefill_partials(
                 + [kv_spec(j) for j in range(ppb)]
             ),
             out_specs=[
-                pl.BlockSpec((1, 1, 1, 1, R), part_map),
-                pl.BlockSpec((1, 1, 1, 1, R), part_map),
-                pl.BlockSpec((1, 1, 1, 1, R, D), acc_map),
+                pl.BlockSpec((1, 1, 1, 1, R, 1), part_map),
+                pl.BlockSpec((1, 1, 1, 1, R, 1), part_map),
+                pl.BlockSpec((1, 1, 1, 1, R, D), part_map),
             ],
             scratch_shapes=[
                 pltpu.VMEM((R, 1), jnp.float32),
@@ -649,16 +650,17 @@ def paged_prefill_partials(
                 pltpu.VMEM((R, D), jnp.float32),
             ],
         ),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=PREFILL_DIM_SEMANTICS),
         out_shape=[
-            jax.ShapeDtypeStruct((B, n_kv, NQ, S, R), jnp.float32),
-            jax.ShapeDtypeStruct((B, n_kv, NQ, S, R), jnp.float32),
+            jax.ShapeDtypeStruct((B, n_kv, NQ, S, R, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, n_kv, NQ, S, R, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, n_kv, NQ, S, R, D), jnp.float32),
         ],
         interpret=resolve_interpret(interpret),
     )(tables3d, kv_lens.astype(jnp.int32), q_start.astype(jnp.int32), qb5,
       *([k_pages] * ppb), *([v_pages] * ppb))
+    return m[..., 0], l[..., 0], acc
 
 
 def paged_prefill_kernel(

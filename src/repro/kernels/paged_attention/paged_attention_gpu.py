@@ -61,7 +61,7 @@ Validation
 ----------
 Off-GPU the kernel runs through the Pallas interpreter (CPU CI exercises
 the full ppb × splits × variant conformance sweep); on a real GPU it
-compiles through ``plgpu.TritonCompilerParams``.  Real-GPU
+compiles through ``plgpu.CompilerParams``.  Real-GPU
 ``interpret=False`` validation is an open ROADMAP item, mirroring the
 TPU-hardware one.
 """
@@ -102,10 +102,10 @@ def _decode_kernel_gpu(
     tables_ref,  # (B, n_blocks, ppb) int32 — rank-clamped table slice
     lens_ref,  # (B,) int32
     q_ref,  # (1, 1, G, D) block for this (b, h)
-    k_ref,  # (num_pages, P, n_kv, D) — whole pool, gathered in-kernel
+    k_ref,  # (num_pages, n_kv, P, D) — whole pool, gathered in-kernel
     v_ref,
-    m_out,  # (1, 1, 1, G)
-    l_out,  # (1, 1, 1, G)
+    m_out,  # (1, 1, 1, G, 1)
+    l_out,  # (1, 1, 1, G, 1)
     acc_out,  # (1, 1, 1, G, D)
     *,
     pages_per_block: int,
@@ -119,7 +119,7 @@ def _decode_kernel_gpu(
     b = pl.program_id(0)
     h = pl.program_id(1)
     s = pl.program_id(2)
-    page_size = k_ref.shape[1]
+    page_size = k_ref.shape[2]
     G, D = q_ref.shape[2], q_ref.shape[3]
 
     q = q_ref[0, 0].astype(jnp.float32) * scale  # (G, D)
@@ -159,8 +159,8 @@ def _decode_kernel_gpu(
             # the paged gather: one dynamically indexed load per scattered
             # page — the table entry computes the tl.load base pointer
             page = tables_ref[b, block_rank, j]
-            ks.append(k_ref[page, :, h, :])  # (P, D)
-            vs.append(v_ref[page, :, h, :])
+            ks.append(k_ref[page, h])  # (P, D)
+            vs.append(v_ref[page, h])
         live = jnp.concatenate(lives)  # (ppb·P,)
         k = jnp.concatenate(ks, axis=0).astype(jnp.float32)
         v = jnp.concatenate(vs, axis=0).astype(jnp.float32)
@@ -185,14 +185,14 @@ def _decode_kernel_gpu(
             jnp.zeros((G, 1), jnp.float32),
             jnp.zeros((G, D), jnp.float32))
     m, l, acc = jax.lax.fori_loop(0, n_trips, body, init)
-    m_out[0, 0, 0] = m[:, 0]
-    l_out[0, 0, 0] = l[:, 0]
+    m_out[0, 0, 0] = m
+    l_out[0, 0, 0] = l
     acc_out[0, 0, 0] = acc
 
 
 def paged_attention_partials_gpu(
     q: jax.Array,  # (B, n_kv, G, D)
-    k_pages: jax.Array,  # (num_pages, P, n_kv, D)
+    k_pages: jax.Array,  # (num_pages, n_kv, P, D)
     v_pages: jax.Array,
     block_tables: jax.Array,  # (B, max_pages)
     lens: jax.Array,  # (B,)
@@ -209,7 +209,7 @@ def paged_attention_partials_gpu(
     `paged_attention_partials`: ((B,n_kv,S,G) m, (B,n_kv,S,G) l,
     (B,n_kv,S,G,D) acc) — f32."""
     B, n_kv, G, D = q.shape
-    num_pages, page_size, _, _ = k_pages.shape
+    num_pages, _, page_size, _ = k_pages.shape
     max_pages = block_tables.shape[1]
 
     ppb, _, S, bps = decode_partition(max_pages, pages_per_block, num_splits)
@@ -225,7 +225,8 @@ def paged_attention_partials_gpu(
 
     whole = lambda arr: pl.BlockSpec(arr.shape,
                                      lambda b, h, s: (0,) * arr.ndim)
-    return pl.pallas_call(
+    part_map = lambda b, h, s: (b, h, s, 0, 0)
+    m, l, acc = pl.pallas_call(
         kernel,
         grid=(B, n_kv, S),
         in_specs=[
@@ -236,19 +237,20 @@ def paged_attention_partials_gpu(
             whole(v_pages),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, 1, G), lambda b, h, s: (b, h, s, 0)),
-            pl.BlockSpec((1, 1, 1, G), lambda b, h, s: (b, h, s, 0)),
-            pl.BlockSpec((1, 1, 1, G, D), lambda b, h, s: (b, h, s, 0, 0)),
+            pl.BlockSpec((1, 1, 1, G, 1), part_map),
+            pl.BlockSpec((1, 1, 1, G, 1), part_map),
+            pl.BlockSpec((1, 1, 1, G, D), part_map),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, n_kv, S, G), jnp.float32),
-            jax.ShapeDtypeStruct((B, n_kv, S, G), jnp.float32),
+            jax.ShapeDtypeStruct((B, n_kv, S, G, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, n_kv, S, G, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, n_kv, S, G, D), jnp.float32),
         ],
-        compiler_params=plgpu.TritonCompilerParams(
+        compiler_params=plgpu.CompilerParams(
             num_warps=_NUM_WARPS, num_stages=_NUM_STAGES),
         interpret=resolve_interpret(interpret, backend="gpu"),
     )(tables3d, lens.astype(jnp.int32), q, k_pages, v_pages)
+    return m[..., 0], l[..., 0], acc
 
 
 def _prefill_kernel_gpu(
@@ -256,9 +258,9 @@ def _prefill_kernel_gpu(
     lens_ref,  # (B,) int32 — kv_lens (cached tokens incl. the chunk)
     qstart_ref,  # (B,) int32 — absolute position of chunk token 0
     q_ref,  # (1, 1, 1, R, D) block for this (b, h, nq)
-    k_ref,  # (num_pages, P, n_kv, D) — whole pool, gathered in-kernel
+    k_ref,  # (num_pages, n_kv, P, D) — whole pool, gathered in-kernel
     v_ref,
-    m_out,  # (1, 1, 1, 1, R)
+    m_out,  # (1, 1, 1, 1, R, 1)
     l_out,
     acc_out,  # (1, 1, 1, 1, R, D)
     *,
@@ -280,7 +282,7 @@ def _prefill_kernel_gpu(
     h = pl.program_id(1)
     nq = pl.program_id(2)
     s = pl.program_id(3)
-    page_size = k_ref.shape[1]
+    page_size = k_ref.shape[2]
     R, D = q_ref.shape[3], q_ref.shape[4]
 
     q = q_ref[0, 0, 0].astype(jnp.float32) * scale  # (R, D)
@@ -306,8 +308,8 @@ def _prefill_kernel_gpu(
             pg = first_page + j
             poss.append(pg * page_size + slot)
             page = tables_ref[b, block_rank, j]
-            ks.append(k_ref[page, :, h, :])  # (P, D)
-            vs.append(v_ref[page, :, h, :])
+            ks.append(k_ref[page, h])  # (P, D)
+            vs.append(v_ref[page, h])
         kvpos = jnp.concatenate(poss)  # (ppb·P,)
         k = jnp.concatenate(ks, axis=0).astype(jnp.float32)
         v = jnp.concatenate(vs, axis=0).astype(jnp.float32)
@@ -333,14 +335,14 @@ def _prefill_kernel_gpu(
             jnp.zeros((R, 1), jnp.float32),
             jnp.zeros((R, D), jnp.float32))
     m, l, acc = jax.lax.fori_loop(0, n_trips, body, init)
-    m_out[0, 0, 0, 0] = m[:, 0]
-    l_out[0, 0, 0, 0] = l[:, 0]
+    m_out[0, 0, 0, 0] = m
+    l_out[0, 0, 0, 0] = l
     acc_out[0, 0, 0, 0] = acc
 
 
 def paged_prefill_partials_gpu(
     q: jax.Array,  # (B, C, n_heads, D)
-    k_pages: jax.Array,  # (num_pages, P, n_kv, D)
+    k_pages: jax.Array,  # (num_pages, n_kv, P, D)
     v_pages: jax.Array,
     block_tables: jax.Array,  # (B, max_pages)
     kv_lens: jax.Array,  # (B,)
@@ -358,7 +360,7 @@ def paged_prefill_partials_gpu(
     contract to the TPU `paged_prefill_partials`; gated by the same
     `ref.paged_prefill_ref` oracle."""
     B, C, n_heads, D = q.shape
-    num_pages, page_size, n_kv, _ = k_pages.shape
+    num_pages, n_kv, page_size, _ = k_pages.shape
     max_pages = block_tables.shape[1]
     G = n_heads // n_kv
 
@@ -378,7 +380,8 @@ def paged_prefill_partials_gpu(
 
     whole = lambda arr: pl.BlockSpec(arr.shape,
                                      lambda b, h, nq, s: (0,) * arr.ndim)
-    return pl.pallas_call(
+    part_map = lambda b, h, nq, s: (b, h, nq, s, 0, 0)
+    m, l, acc = pl.pallas_call(
         kernel,
         grid=(B, n_kv, NQ, S),
         in_specs=[
@@ -390,21 +393,21 @@ def paged_prefill_partials_gpu(
             whole(v_pages),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, 1, 1, R), lambda b, h, nq, s: (b, h, nq, s, 0)),
-            pl.BlockSpec((1, 1, 1, 1, R), lambda b, h, nq, s: (b, h, nq, s, 0)),
-            pl.BlockSpec((1, 1, 1, 1, R, D),
-                         lambda b, h, nq, s: (b, h, nq, s, 0, 0)),
+            pl.BlockSpec((1, 1, 1, 1, R, 1), part_map),
+            pl.BlockSpec((1, 1, 1, 1, R, 1), part_map),
+            pl.BlockSpec((1, 1, 1, 1, R, D), part_map),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, n_kv, NQ, S, R), jnp.float32),
-            jax.ShapeDtypeStruct((B, n_kv, NQ, S, R), jnp.float32),
+            jax.ShapeDtypeStruct((B, n_kv, NQ, S, R, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, n_kv, NQ, S, R, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, n_kv, NQ, S, R, D), jnp.float32),
         ],
-        compiler_params=plgpu.TritonCompilerParams(
+        compiler_params=plgpu.CompilerParams(
             num_warps=_NUM_WARPS, num_stages=_NUM_STAGES),
         interpret=resolve_interpret(interpret, backend="gpu"),
     )(tables3d, kv_lens.astype(jnp.int32), q_start.astype(jnp.int32), qb5,
       k_pages, v_pages)
+    return m[..., 0], l[..., 0], acc
 
 
 def paged_prefill_kernel_gpu(
@@ -438,7 +441,7 @@ def paged_prefill_kernel_gpu(
 
 def paged_attention_kernel_gpu(
     q: jax.Array,  # (B, n_kv, G, D) — q heads grouped by kv head
-    k_pages: jax.Array,  # (num_pages, P, n_kv, D)
+    k_pages: jax.Array,  # (num_pages, n_kv, P, D)
     v_pages: jax.Array,
     block_tables: jax.Array,  # (B, max_pages) int32 (may contain -1)
     lens: jax.Array,  # (B,)

@@ -43,9 +43,18 @@ def ring_slot_positions(lens: jax.Array, page_size: int, ring: int,
     return pos  # negative ⇒ slot never written
 
 
+def gather_pages(pages: jax.Array, tables: jax.Array) -> jax.Array:
+    """Alg.1 GATHER: (num_pages, n_kv, P, D) pool × (B, n) in-range page
+    ids → contiguous (B, n·P, n_kv, D) K or V."""
+    B, n = tables.shape
+    _, n_kv, P, D = pages.shape
+    g = pages[tables].transpose(0, 1, 3, 2, 4)  # (B, n, P, n_kv, D)
+    return g.reshape(B, n * P, n_kv, D)
+
+
 def paged_attention_ref(
     q: jax.Array,  # (B, n_heads, head_dim) — one query token per sequence
-    k_pages: jax.Array,  # (num_pages, page_size, n_kv_heads, head_dim)
+    k_pages: jax.Array,  # (num_pages, n_kv_heads, page_size, head_dim)
     v_pages: jax.Array,
     block_tables: jax.Array,  # (B, max_pages) int32, NULL = -1
     lens: jax.Array,  # (B,) int32 — cached tokens incl. the current one
@@ -76,13 +85,13 @@ def _gathered_scores(q, k_pages, v_pages, block_tables, lens, *,
     Returns (scores (B,Hkv,G,S) f32, live (B,S), v (B,S,Hkv,D)).
     """
     B, n_heads, head_dim = q.shape
-    num_pages, page_size, n_kv, _ = k_pages.shape
+    num_pages, n_kv, page_size, _ = k_pages.shape
     max_pages = block_tables.shape[1]
     S = max_pages * page_size
 
     safe = jnp.clip(block_tables, 0, num_pages - 1)
-    k = jax.lax.optimization_barrier(k_pages[safe].reshape(B, S, n_kv, head_dim))
-    v = jax.lax.optimization_barrier(v_pages[safe].reshape(B, S, n_kv, head_dim))
+    k = jax.lax.optimization_barrier(gather_pages(k_pages, safe))
+    v = jax.lax.optimization_barrier(gather_pages(v_pages, safe))
     if kv_scale > 0:
         k = (k.astype(jnp.float32) * kv_scale).astype(q.dtype)
         v = (v.astype(jnp.float32) * kv_scale).astype(q.dtype)
@@ -111,7 +120,7 @@ def _gathered_scores(q, k_pages, v_pages, block_tables, lens, *,
 
 def paged_prefill_ref(
     q: jax.Array,  # (B, C, n_heads, head_dim) — one prompt *chunk* per seq
-    k_pages: jax.Array,  # (num_pages, page_size, n_kv, head_dim)
+    k_pages: jax.Array,  # (num_pages, n_kv, page_size, head_dim)
     v_pages: jax.Array,
     block_tables: jax.Array,  # (B, max_pages) int32, NULL = -1
     kv_lens: jax.Array,  # (B,) — cached tokens incl. the current chunk
@@ -141,13 +150,13 @@ def paged_prefill_ref(
     """
     B, C, n_heads, head_dim = q.shape
     scale = scale if scale is not None else 1.0 / np.sqrt(head_dim)
-    num_pages, page_size, n_kv, _ = k_pages.shape
+    num_pages, n_kv, page_size, _ = k_pages.shape
     S = block_tables.shape[1] * page_size
     g = n_heads // n_kv
 
     safe = jnp.clip(block_tables, 0, num_pages - 1)
-    k = jax.lax.optimization_barrier(k_pages[safe].reshape(B, S, n_kv, head_dim))
-    v = jax.lax.optimization_barrier(v_pages[safe].reshape(B, S, n_kv, head_dim))
+    k = jax.lax.optimization_barrier(gather_pages(k_pages, safe))
+    v = jax.lax.optimization_barrier(gather_pages(v_pages, safe))
     if kv_scale > 0:
         k = (k.astype(jnp.float32) * kv_scale).astype(q.dtype)
         v = (v.astype(jnp.float32) * kv_scale).astype(q.dtype)
@@ -199,8 +208,8 @@ def paged_prefill_partials_ref(
     """
     NEG_INF = -1e30
     B, C, n_heads, head_dim = q.shape
-    n_kv = k_pages.shape[2]
-    page_size = k_pages.shape[1]
+    n_kv = k_pages.shape[1]
+    page_size = k_pages.shape[2]
     max_pages = block_tables.shape[1]
     S_tok = max_pages * page_size
     scale = float(scale if scale is not None else 1.0 / np.sqrt(head_dim))
@@ -216,8 +225,8 @@ def paged_prefill_partials_ref(
     qpad = jnp.pad(q, ((0, 0), (0, Cp - C), (0, 0), (0, 0)))
     qg = qpad.reshape(B, nq, qb, n_kv, g, head_dim) * scale
     safe = jnp.clip(block_tables, 0, k_pages.shape[0] - 1)
-    k = k_pages[safe].reshape(B, S_tok, n_kv, head_dim)
-    v = v_pages[safe].reshape(B, S_tok, n_kv, head_dim)
+    k = gather_pages(k_pages, safe)
+    v = gather_pages(v_pages, safe)
     if kv_scale > 0:
         k = (k.astype(jnp.float32) * kv_scale).astype(q.dtype)
         v = (v.astype(jnp.float32) * kv_scale).astype(q.dtype)
@@ -263,7 +272,7 @@ def paged_prefill_partials_ref(
 
 def paged_attention_partials_ref(
     q: jax.Array,  # (B, n_heads, head_dim)
-    k_pages: jax.Array,  # (num_pages, page_size, n_kv, head_dim)
+    k_pages: jax.Array,  # (num_pages, n_kv, page_size, head_dim)
     v_pages: jax.Array,
     block_tables: jax.Array,  # (B, max_pages)
     lens: jax.Array,  # (B,)
@@ -290,8 +299,8 @@ def paged_attention_partials_ref(
     """
     NEG_INF = -1e30
     B, n_heads, head_dim = q.shape
-    n_kv = k_pages.shape[2]
-    page_size = k_pages.shape[1]
+    n_kv = k_pages.shape[1]
+    page_size = k_pages.shape[2]
     max_pages = block_tables.shape[1]
     S_tok = max_pages * page_size
     scale = float(scale if scale is not None else 1.0 / np.sqrt(head_dim))

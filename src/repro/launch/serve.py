@@ -1,11 +1,14 @@
 """Serving launcher: continuous-batching engine over the paged KV cache.
 
 Runs the full engine loop (admission → prefill → paged decode → sampling)
-on CPU with a reduced config; on TPU the same engine runs with
-``impl="pallas"`` and the mesh-sharded decode schemes.
+with the Pallas kernels (``--impl pallas``, the default): compiled on a
+TPU, interpreted elsewhere.  ``--smoke`` serves the reduced config;
+without it the published widths, optionally with the depth cut.
 
 Usage:
   python -m repro.launch.serve --arch granite-8b --smoke --requests 8
+  python -m repro.launch.serve --arch granite-8b --layers 16 \
+      --dtype bfloat16 --max-seq-len 2048 --pool-tokens 16384
 """
 
 from __future__ import annotations
@@ -13,8 +16,10 @@ from __future__ import annotations
 import argparse
 import time
 
+import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.serving import Engine, Request
 
@@ -32,13 +37,24 @@ def main() -> None:
     ap.add_argument("--no-paged", action="store_true",
                     help="contiguous baseline (the paper's comparison)")
     ap.add_argument("--temperature", type=float, default=0.0)
+    ap.add_argument("--impl", default="pallas", choices=["pallas", "ref"],
+                    help="attention ops: Pallas kernels or the jnp oracle")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the depth to this many layers")
+    ap.add_argument("--dtype", default="float32",
+                    choices=["float32", "bfloat16"],
+                    help="weights, activations and KV pages")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke()
+    if args.layers is not None:
+        cfg = cfg.replace(n_layers=args.layers)
     eng = Engine(cfg, max_slots=args.max_slots, max_seq_len=args.max_seq_len,
-                 pool_tokens=args.pool_tokens, paged=not args.no_paged)
+                 pool_tokens=args.pool_tokens, paged=not args.no_paged,
+                 impl=args.impl, dtype=jnp.dtype(args.dtype))
 
     rng = np.random.default_rng(0)
     reqs, extras = [], []

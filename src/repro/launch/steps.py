@@ -221,7 +221,7 @@ def decode_state_shardings(model, plan: Plan, state_abstract) -> Dict:
                 continue
             # tp: kv-head dim over "model"; kvp: pages striped over kv axes
             kvh = "model" if plan.scheme == "tp" else None
-            out[key] = _ns(plan, None, pa, None, kvh, None)
+            out[key] = _ns(plan, None, pa, kvh, None, None)
         elif key == "tables":
             out[key] = _ns(plan, ba, kv if plan.scheme == "kvp" else None,
                            None)

@@ -130,7 +130,7 @@ class EncDecModel:
                 return jax.ShapeDtypeStruct(shape, dt)
             return jnp.zeros(shape, dt)
 
-        pool = (cfg.n_layers, num_pages, ps, Hkv, hd)
+        pool = (cfg.n_layers, num_pages, Hkv, ps, hd)
         pool_dt = jnp.int8 if cfg.kv_dtype == "int8" else dtype
         return {
             "pos": arr((B,), jnp.int32),

@@ -10,6 +10,7 @@ keeping init, sharding, and dry-run shapes impossible to de-synchronise.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -28,26 +29,35 @@ def _is_spec(x) -> bool:
     return isinstance(x, ParamSpec)
 
 
+def _init_leaf(spec: ParamSpec, key: jax.Array, dtype):
+    if spec.init == "zeros":
+        return jnp.zeros(spec.shape, dtype)
+    if spec.init == "ones":
+        return jnp.ones(spec.shape, dtype)
+    if spec.init == "a_log":  # RG-LRU Λ init: a ∈ [0.9, 0.999]
+        u = jax.random.uniform(key, spec.shape, jnp.float32, 0.9, 0.999)
+        return jnp.log(u / (1 - u)).astype(dtype)
+    scale = spec.scale
+    if spec.init == "small_normal":
+        scale = spec.scale / np.sqrt(max(spec.shape[-1], 1))
+    return (jax.random.normal(key, spec.shape, jnp.float32) * scale
+            ).astype(dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 2))
+def _init_leaves(specs: Tuple[ParamSpec, ...], rng: jax.Array, dtype):
+    # one program for the whole tree: each f32 draw fuses into its cast,
+    # so only the ``dtype`` leaves are ever resident (a (layers, d_model,
+    # d_ff) stack drawn eagerly would hold two f32 copies of itself), and
+    # a model compiles one program instead of one per leaf shape
+    keys = jax.random.split(rng, len(specs))
+    return [_init_leaf(s, keys[i], dtype) for i, s in enumerate(specs)]
+
+
 def materialize(spec_tree, rng: jax.Array, dtype=jnp.float32):
     leaves, treedef = jax.tree_util.tree_flatten(spec_tree, is_leaf=_is_spec)
-    keys = jax.random.split(rng, len(leaves))
-
-    def mk(spec: ParamSpec, key):
-        if spec.init == "zeros":
-            return jnp.zeros(spec.shape, dtype)
-        if spec.init == "ones":
-            return jnp.ones(spec.shape, dtype)
-        if spec.init == "a_log":  # RG-LRU Λ init: a ∈ [0.9, 0.999]
-            u = jax.random.uniform(key, spec.shape, jnp.float32, 0.9, 0.999)
-            return jnp.log(u / (1 - u)).astype(dtype)
-        scale = spec.scale
-        if spec.init == "small_normal":
-            scale = spec.scale / np.sqrt(max(spec.shape[-1], 1))
-        return (jax.random.normal(key, spec.shape, jnp.float32) * scale
-                ).astype(dtype)
-
     return jax.tree_util.tree_unflatten(
-        treedef, [mk(s, k) for s, k in zip(leaves, keys)])
+        treedef, _init_leaves(tuple(leaves), rng, jnp.dtype(dtype)))
 
 
 def axes_tree(spec_tree):

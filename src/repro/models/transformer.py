@@ -227,7 +227,7 @@ class TransformerModel:
 
         st: Dict[str, Any] = {"pos": arr((B,), jnp.int32)}
         if self.n_attn_layers:
-            pool = (self.n_attn_layers, num_pages, ps, Hkv, hd)
+            pool = (self.n_attn_layers, num_pages, Hkv, ps, hd)
             pool_dt = jnp.int8 if cfg.kv_dtype == "int8" else dtype
             st["k_pages"] = arr(pool, pool_dt)
             st["v_pages"] = arr(pool, pool_dt)
@@ -294,9 +294,7 @@ class TransformerModel:
         # differs (pools are indexed per attention layer), and prefill is
         # lowered once per shape — compile cost is acceptable even at 126
         # layers because each layer body is identical HLO.
-        layer_params = self._per_layer_params(params)
-        for li, code in enumerate(codes):
-            p = layer_params[li]
+        for code, p in zip(codes, self._per_layer_params(params)):
             h = layers.apply_norm(p["ln1"], x)
             if code in ATTN_CODES:
                 w = cfg.window if code == "W" else 0
@@ -403,9 +401,7 @@ class TransformerModel:
         st = dict(state)
         ai = ci = 0
         new_k, new_v, new_ck, new_cv = [], [], [], []
-        layer_params = self._per_layer_params(params)
-        for li, code in enumerate(codes):
-            p = layer_params[li]
+        for code, p in zip(codes, self._per_layer_params(params)):
             h = layers.apply_norm(p["ln1"], x)
             if code in ATTN_CODES:
                 w = cfg.window if code == "W" else 0
@@ -660,15 +656,15 @@ class TransformerModel:
         return jnp.einsum("sbhk,hkd->bsd", hs, p["wo"]), state
 
     def _per_layer_params(self, params: Dict):
-        """List of per-layer param trees in layer order (unstacked views)."""
-        out = []
+        """Per-layer param trees in layer order, sliced lazily: outside
+        jit a slice of the stacked groups is a copy, so slicing every
+        layer up front would hold a second copy of the weights."""
         for g in range(self.n_groups):
             for j, code in enumerate(self.unit):
-                out.append(jax.tree_util.tree_map(
-                    lambda a: a[g], params["groups"][f"{j}{code}"]))
+                yield jax.tree_util.tree_map(
+                    lambda a: a[g], params["groups"][f"{j}{code}"])
         for j, code in enumerate(self.rem_codes):
-            out.append(params["rem"][f"{j}{code}"])
-        return out
+            yield params["rem"][f"{j}{code}"]
 
     def decode_step(self, params: Dict, tokens: jax.Array, state: Dict,
                     impl: str = "ref", attn_ctx: Optional[Dict] = None,

@@ -26,7 +26,8 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core.paging import HostPageManager
 from repro.core.prefix_cache import PrefixCache
-from repro.errors import (EngineConfigError, EngineError, InternalError,
+from repro.errors import (DeadlineExceeded, EngineConfigError,
+                          EngineError, InternalError,
                           InvalidRequest, NumericsError, PoolExhausted,
                           RequestTooLong, SchedulerInvariantError,
                           TransientDeviceError)
@@ -196,7 +197,7 @@ class Engine:
         n_attn = getattr(m, "n_attn_layers", 0)
         if n_attn:
             if self.paged:
-                pool = (n_attn, self.num_pages, ps, Hkv, hd)
+                pool = (n_attn, self.num_pages, Hkv, ps, hd)
                 pool_dt = (jnp.int8 if cfg.kv_dtype == "int8"
                            else self.dtype)
                 st["k_pages"] = jnp.zeros(pool, pool_dt)
@@ -260,14 +261,21 @@ class Engine:
     def generate(self, reqs: List[Request],
                  extras: Optional[List[Optional[Dict]]] = None,
                  max_steps: int = 100_000) -> List[Request]:
-        """Blocking helper: run until the given requests all finish."""
+        """Blocking helper: run until the given requests all reach a
+        terminal state.  Raises ``DeadlineExceeded`` when ``max_steps``
+        engine steps leave any of them unfinished."""
         extras = extras or [None] * len(reqs)
         for r, e in zip(reqs, extras):
             self.add_request(r, e)
         for _ in range(max_steps):
             if all(r.done for r in reqs):
-                break
+                return reqs
             self.step()
+        if not all(r.done for r in reqs):
+            raise DeadlineExceeded(
+                f"generate: {sum(not r.done for r in reqs)} of {len(reqs)} "
+                f"requests unfinished after max_steps={max_steps}",
+                max_steps=max_steps)
         return reqs
 
     # ------------------------------------------------------------------
@@ -686,8 +694,8 @@ class Engine:
         Hkv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
         tmp_state: Dict[str, Any] = {
             "pos": jnp.asarray(lens),
-            "k_pages": jnp.zeros((n_attn, B * pp, ps, Hkv, hd), self.dtype),
-            "v_pages": jnp.zeros((n_attn, B * pp, ps, Hkv, hd), self.dtype),
+            "k_pages": jnp.zeros((n_attn, B * pp, Hkv, ps, hd), self.dtype),
+            "v_pages": jnp.zeros((n_attn, B * pp, Hkv, ps, hd), self.dtype),
             "tables": tmp_tables,
         }
         logits, new_st = self.model.prefill(
@@ -777,12 +785,10 @@ class Engine:
         params = self.params
         pos = st["pos"]
         x = layers.embed_tokens(params["embed"], tokens)
-        layer_params = m._per_layer_params(params)
         codes = cfg.pattern()
         ai = 0
         new_st = dict(st)
-        for li, code in enumerate(codes):
-            p = layer_params[li]
+        for code, p in zip(codes, m._per_layer_params(params)):
             h = layers.apply_norm(p["ln1"], x)
             if code in "AW":
                 w = cfg.window if code == "W" else 0

@@ -2,12 +2,6 @@ import jax
 import numpy as np
 import pytest
 
-try:  # property tests use hypothesis when available ...
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:  # ... else a deterministic-sampling stand-in
-    import _hypothesis_stub
-    _hypothesis_stub._install()
-
 # Smoke tests and benches run on the single real CPU device; ONLY the
 # dry-run (repro.launch.dryrun, run as its own process) forces 512 devices.
 jax.config.update("jax_enable_x64", False)

@@ -21,7 +21,7 @@ def bad_dim_semantics(q):
         in_specs=[pl.BlockSpec(q.shape, lambda i, j: (0, 0))],
         out_specs=pl.BlockSpec(q.shape, lambda i, j: (0, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=BAD_DIM_SEMANTICS),
     )(q)
 

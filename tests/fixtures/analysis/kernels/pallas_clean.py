@@ -26,7 +26,7 @@ def good_dim_semantics(q):
         in_specs=[pl.BlockSpec(q.shape, lambda i, j: (0, 0))],
         out_specs=pl.BlockSpec(q.shape, lambda i, j: (0, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=DIM_SEMANTICS),
     )(q)
 

@@ -40,7 +40,7 @@ def test_prefill_writes_then_gather_roundtrip(rng):
     k = jax.random.normal(ks[0], (B, S, Hkv, D))
     v = jax.random.normal(ks[1], (B, S, Hkv, D))
     lens = jnp.asarray([S, 21], jnp.int32)
-    pages = jnp.zeros((B * pp + 2, ps, Hkv, D))
+    pages = jnp.zeros((B * pp + 2, Hkv, ps, D))
     tables = (jnp.arange(B * pp, dtype=jnp.int32).reshape(B, pp) + 2)
     kp, vp = kvcache.write_layer_prefill(pages, pages, tables, k, v, lens)
     kg, vg = kvcache.gather_layer(kp, vp, tables, S)
@@ -55,7 +55,7 @@ def test_prefill_writes_then_gather_roundtrip(rng):
 def test_decode_write_then_attend_matches_contiguous(rng):
     B, Hkv, H, D, ps, mp = 2, 2, 4, 16, 8, 4
     ks = jax.random.split(rng, 6)
-    kp = jnp.zeros((B * mp, ps, Hkv, D))
+    kp = jnp.zeros((B * mp, Hkv, ps, D))
     vp = jnp.zeros_like(kp)
     tables = jnp.arange(B * mp, dtype=jnp.int32).reshape(B, mp)
     kc = jnp.zeros((B, mp * ps, Hkv, D))
@@ -103,7 +103,7 @@ def test_decode_attention_window_vs_truncated_contiguous(rng):
     T = 40
     kc = jax.random.normal(ks[0], (B, T, Hkv, D))
     vc = jax.random.normal(ks[1], (B, T, Hkv, D))
-    kp = jnp.zeros((B * ring, ps, Hkv, D))
+    kp = jnp.zeros((B * ring, Hkv, ps, D))
     vp = jnp.zeros_like(kp)
     tables = jnp.arange(B * ring, dtype=jnp.int32).reshape(B, ring)
     state = type("S", (), {"block_tables": tables})()

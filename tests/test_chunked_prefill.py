@@ -52,8 +52,8 @@ def make_prefill_case(seed, B, H, Hkv, D, page, max_pages, kv_lens, q_start):
     q_start = np.asarray(q_start, np.int32)
     C = int((kv_lens - q_start).max())
     q = jnp.asarray(rng.randn(B, C, H, D), jnp.float32)
-    kp = jnp.asarray(rng.randn(num_pages, page, Hkv, D), jnp.float32)
-    vp = jnp.asarray(rng.randn(num_pages, page, Hkv, D), jnp.float32)
+    kp = jnp.asarray(rng.randn(num_pages, Hkv, page, D), jnp.float32)
+    vp = jnp.asarray(rng.randn(num_pages, Hkv, page, D), jnp.float32)
     perm = rng.permutation(num_pages)
     tables = np.full((B, max_pages), -1, np.int32)
     k = 0
@@ -150,7 +150,7 @@ def _mk_state(model, cfg, B, pages_per_seq=8):
     n_attn = getattr(model, "n_attn_layers", 0)
     Hkv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
     num_pages = B * pages_per_seq + 1
-    st["k_pages"] = jnp.zeros((n_attn, num_pages, cfg.page_size, Hkv, hd))
+    st["k_pages"] = jnp.zeros((n_attn, num_pages, Hkv, cfg.page_size, hd))
     st["v_pages"] = jnp.zeros_like(st["k_pages"])
     st["tables"] = jnp.asarray(
         np.arange(B * pages_per_seq, dtype=np.int32

@@ -56,8 +56,8 @@ def _conformance_case(rng, variant):
         num_pages = B * mp
         ks = jax.random.split(rng, 3)
         q = jax.random.normal(ks[0], (B, H, D))
-        kp = jax.random.normal(ks[1], (num_pages, page, Hkv, D))
-        vp = jax.random.normal(ks[2], (num_pages, page, Hkv, D))
+        kp = jax.random.normal(ks[1], (num_pages, Hkv, page, D))
+        vp = jax.random.normal(ks[2], (num_pages, Hkv, page, D))
         tables = jnp.arange(num_pages, dtype=jnp.int32).reshape(B, mp)
         lens = jnp.asarray([65, 9], jnp.int32)
         return q, kp, vp, tables, lens, dict(window=window)

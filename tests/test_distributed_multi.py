@@ -24,6 +24,7 @@ def run_sub(body: str):
         import sys; sys.path.insert(0, %r)
         import jax, jax.numpy as jnp, numpy as np
         from repro.distributed.sharding import use_mesh, DEFAULT_RULES
+        from repro.launch.mesh import make_mesh
     """) % os.path.abspath(SRC) + textwrap.dedent(body)
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=900)
@@ -38,7 +39,7 @@ def test_ep_moe_matches_reference():
         from repro.models import moe
         from repro.models.api import build_model
         from repro.distributed import ep
-        mesh = jax.make_mesh((4,2), ("data","model"))
+        mesh = make_mesh((4,2), ("data","model"))
         cfg = get_smoke('olmoe-1b-7b').replace(moe_capacity=0.0)
         rules = DEFAULT_RULES.extend(batch=("data",))
         m = build_model(cfg)
@@ -58,7 +59,7 @@ def test_ep_moe_matches_reference():
 def test_ring_attention_matches_dense():
     run_sub("""
         from repro.core.attention import prefill_attention
-        mesh = jax.make_mesh((2,4), ("data","model"))
+        mesh = make_mesh((2,4), ("data","model"))
         rules = DEFAULT_RULES.extend(batch=("data",), seq=("model",),
                                      heads=None, kv_heads=None)
         rng = jax.random.PRNGKey(0)
@@ -84,13 +85,13 @@ def test_kvp_flash_decoding_matches_local():
     run_sub("""
         from repro.core.attention import decode_attention
         from repro.distributed.collectives import decode_attention_sharded
-        mesh = jax.make_mesh((2,4), ("data","model"))
+        mesh = make_mesh((2,4), ("data","model"))
         B, Hkv, G, D, ps, pps, n_sh = 2, 2, 4, 16, 4, 8, 4
         num_pages = B * pps
         ks = jax.random.split(jax.random.PRNGKey(0), 4)
         q4 = jax.random.normal(ks[0], (B, Hkv, G, D))
-        kp = jax.random.normal(ks[1], (num_pages, ps, Hkv, D))
-        vp = jax.random.normal(ks[2], (num_pages, ps, Hkv, D))
+        kp = jax.random.normal(ks[1], (num_pages, Hkv, ps, D))
+        vp = jax.random.normal(ks[2], (num_pages, Hkv, ps, D))
         lens = jnp.asarray([29, 17], jnp.int32)
         logical = jnp.arange(B*pps, dtype=jnp.int32).reshape(B, pps)
         ref = decode_attention(q4.reshape(B, Hkv*G, D), kp, vp, logical,
@@ -123,7 +124,7 @@ def test_serve_step_lowers_on_8dev_mesh():
         from repro.configs import get_smoke
         from repro.configs.base import RunConfig
         from repro.launch.steps import build_step, plan_for
-        mesh = jax.make_mesh((2,4), ("data","model"))
+        mesh = make_mesh((2,4), ("data","model"))
         cfg = get_smoke('granite-8b')
         run = RunConfig(model=cfg, seq_len=64, global_batch=4, kind='decode')
         for ws in (False, True):

@@ -288,3 +288,12 @@ def test_pallas_decode_engine_matches_ref_engine():
         eng.generate([req])
         outs.append(list(req.output))
     assert outs[0] == outs[1]
+
+
+def test_generate_raises_when_max_steps_leaves_requests_unfinished():
+    from repro.serving import DeadlineExceeded
+    eng = make_engine()
+    reqs = [Request(prompt=[1, 2, 3], max_new_tokens=8)]
+    with pytest.raises(DeadlineExceeded, match="1 of 1 requests unfinished"):
+        eng.generate(reqs, max_steps=3)
+    assert not reqs[0].done

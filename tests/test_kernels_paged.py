@@ -41,8 +41,8 @@ def make_case(rng, B, H, Hkv, D, page, max_pages, lens, dtype=jnp.float32,
     num_pages = B * max_pages + 3
     ks = jax.random.split(rng, 3)
     q = jax.random.normal(ks[0], (B, H, D), dtype)
-    kp = jax.random.normal(ks[1], (num_pages, page, Hkv, D), dtype)
-    vp = jax.random.normal(ks[2], (num_pages, page, Hkv, D), dtype)
+    kp = jax.random.normal(ks[1], (num_pages, Hkv, page, D), dtype)
+    vp = jax.random.normal(ks[2], (num_pages, Hkv, page, D), dtype)
     # shuffled physical pages (scattered layout — the paper's whole point)
     perm = np.random.RandomState(0).permutation(num_pages)
     tables = np.full((B, max_pages), -1, np.int32)
@@ -92,8 +92,8 @@ def test_kernel_window_softcap(rng, window, softcap, backend):
         num_pages = B * mp
         ks = jax.random.split(rng, 3)
         q = jax.random.normal(ks[0], (B, H, D))
-        kp = jax.random.normal(ks[1], (num_pages, page, Hkv, D))
-        vp = jax.random.normal(ks[2], (num_pages, page, Hkv, D))
+        kp = jax.random.normal(ks[1], (num_pages, Hkv, page, D))
+        vp = jax.random.normal(ks[2], (num_pages, Hkv, page, D))
         tables = jnp.arange(num_pages, dtype=jnp.int32).reshape(B, mp)
         lens = jnp.asarray(lens, jnp.int32)
     else:
@@ -174,8 +174,8 @@ def _variant_case(rng, variant):
         num_pages = B * mp
         ks = jax.random.split(rng, 3)
         q = jax.random.normal(ks[0], (B, H, D))
-        kp = jax.random.normal(ks[1], (num_pages, page, Hkv, D))
-        vp = jax.random.normal(ks[2], (num_pages, page, Hkv, D))
+        kp = jax.random.normal(ks[1], (num_pages, Hkv, page, D))
+        vp = jax.random.normal(ks[2], (num_pages, Hkv, page, D))
         tables = jnp.arange(num_pages, dtype=jnp.int32).reshape(B, mp)
         lens = jnp.asarray([65, 9], jnp.int32)
         return q, kp, vp, tables, lens, dict(window=window)
@@ -333,3 +333,18 @@ def test_backends_agree_bitwise_partition(rng):
                                 interpret=True, pages_per_block=ppb,
                                 num_splits=ns, backend="gpu")
         assert float(jnp.max(jnp.abs(o_tpu - o_gpu))) <= 1e-5
+
+
+def test_interpreted_kernel_refused_on_tpu(monkeypatch):
+    """On a TPU host an interpreted kernel is an error, not a fallback —
+    for an explicit interpret=True and for a lowering that would
+    auto-resolve to the interpreter (the GPU one)."""
+    import repro.kernels as kernels
+    from repro.errors import EngineConfigError
+
+    monkeypatch.setattr(kernels, "_on_platform", lambda p: p == "tpu")
+    assert kernels.resolve_interpret(None) is False
+    with pytest.raises(EngineConfigError, match="interpret mode on a TPU"):
+        kernels.resolve_interpret(True)
+    with pytest.raises(EngineConfigError, match="interpret mode on a TPU"):
+        kernels.resolve_interpret(None, backend="gpu")

@@ -29,7 +29,7 @@ def test_logical_spec_drops_duplicate_mesh_axes():
 
 
 def test_logical_spec_divisibility_fallback():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh()
     rules = AxisRules({"heads": ("model",)})
     # 1-device mesh: any size divides; now a fake check with shape
     spec = logical_spec(("heads",), rules, mesh=mesh, shape=(7,))
@@ -46,7 +46,7 @@ def test_plan_scheme_selection():
     from repro.configs import get_config, make_run
     from repro.launch.steps import plan_for
     import os
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh()
     # kv=8 % model=1 == 0 -> tp on a 1-wide model axis
     run = make_run(get_config("granite-8b"), "decode_32k")
     assert plan_for(run, mesh).scheme == "tp"
