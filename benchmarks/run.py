@@ -7,7 +7,6 @@
   tbl_decode_blocks  pages_per_block × num_splits kernel sweep  (kernel v2)
   tbl_perplexity  numerical equivalence of eval loss            (§IV-B3)
   mixed_batch     throughput under a fixed memory budget        (§IV b)
-  roofline        dry-run roofline aggregation                  (§Roofline)
 
 Prints ``name,us_per_call,derived`` CSV at the end (harness contract).
 """
@@ -36,8 +35,8 @@ def main() -> None:
     from repro.compile_cache import enable_compile_cache
     enable_compile_cache()
     from benchmarks import (fig3_latency, fig4_decode, fig12_memory,
-                            mixed_batch, roofline, tbl_allocator,
-                            tbl_decode_blocks, tbl_pagesize, tbl_perplexity)
+                            mixed_batch, tbl_allocator, tbl_decode_blocks,
+                            tbl_pagesize, tbl_perplexity)
     benches = {
         "fig3_latency": fig3_latency.run,
         "fig4_decode": fig4_decode.run,
@@ -47,7 +46,6 @@ def main() -> None:
         "tbl_pagesize": tbl_pagesize.run,
         "tbl_perplexity": tbl_perplexity.run,
         "mixed_batch": mixed_batch.run,
-        "roofline": roofline.run,
     }
     only = [s for s in args.only.split(",") if s]
     csv = ["name,us_per_call,derived"]

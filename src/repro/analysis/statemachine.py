@@ -164,7 +164,8 @@ class LifecycleDriver:
         for attr in ("max_slots", "max_seq_len", "headroom",
                      "prefill_chunk", "max_waiting", "admit_watermark",
                      "preempted", "prefill_stalls", "shed", "failed",
-                     "cancelled", "deadline_misses"):
+                     "cancelled", "deadline_misses", "admitted",
+                     "queue_wait_ns"):
             setattr(sched, attr, getattr(s, attr))
         sched.waiting = [by_rid[r.rid] for r in s.waiting]
         sched.running = {slot: by_rid[r.rid]

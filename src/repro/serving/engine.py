@@ -12,6 +12,13 @@ memory win over max-length pre-allocation.
 
 The contiguous baseline (``paged=False``) allocates the paper's comparison
 target instead: per-slot max-length buffers.
+
+Every phase of a step runs inside a host span (``TraceAnnotation``, named
+``engine.*``; README "Tracing a step").  A span costs about a microsecond
+when no profiler runs; under one it lands on the host plane, on the clock
+of the device's program and op lines, so a device idle gap is named by
+the host work in flight.  Spans open only in host code: inside a jitted
+function one would fire only while it is traced.
 """
 
 from __future__ import annotations
@@ -22,6 +29,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core.paging import HostPageManager
@@ -185,7 +193,8 @@ class Engine:
         self.state = self._init_state()
         self._slot_extra: Dict[int, Dict] = {}
         self.steps = 0
-        self.stats: Dict[str, int] = {"transient_retries": 0}
+        self.stats: Dict[str, int] = {"transient_retries": 0,
+                                      "first_tokens": 0, "prefill_ns": 0}
         self._jit_decode = jax.jit(self._decode_fn, static_argnames=())
 
     # ------------------------------------------------------------------
@@ -310,31 +319,34 @@ class Engine:
                 f"unstructured failure escaped engine step: {e!r}") from e
 
     def _step_impl(self) -> List[Request]:
-        self.steps += 1
-        self.scheduler.check_deadlines(self.steps)
-        admitted = self.scheduler.admit()
-        finished: List[Request] = []
-        if self.prefill_chunk is None:
-            if admitted:
-                self._dispatch("prefill", self._prefill, admitted)
-                # prefill's sampled token may already hit EOS / max_new
+        with TraceAnnotation("engine.step"):
+            self.steps += 1
+            with TraceAnnotation("engine.sched.admit"):
+                self.scheduler.check_deadlines(self.steps)
+                admitted = self.scheduler.admit()
+            finished: List[Request] = []
+            if self.prefill_chunk is None:
+                if admitted:
+                    self._dispatch("prefill", self._prefill, admitted)
+                    # prefill's sampled token may already hit EOS / max_new
+                    finished += self._finish_done()
+            elif any(r.status is Status.PREFILLING
+                     for r in self.scheduler.running.values()):
+                self._dispatch("prefill", self._prefill_chunk_step)
                 finished += self._finish_done()
-        elif any(r.status is Status.PREFILLING
-                 for r in self.scheduler.running.values()):
-            self._dispatch("prefill", self._prefill_chunk_step)
-            finished += self._finish_done()
-        if any(r.status is Status.RUNNING
-               for r in self.scheduler.running.values()):
-            if self.paged:
-                self.scheduler.extend_for_decode()
-            # extend may have failed the last decoder (starvation) —
-            # re-check before dispatching an empty decode sub-batch
             if any(r.status is Status.RUNNING
                    for r in self.scheduler.running.values()):
-                self._dispatch("decode", self._decode)
-                finished += self._finish_done()
-        finished += self._drain_failed()
-        return finished
+                if self.paged:
+                    with TraceAnnotation("engine.sched.extend"):
+                        self.scheduler.extend_for_decode()
+                # extend may have failed the last decoder (starvation) —
+                # re-check before dispatching an empty decode sub-batch
+                if any(r.status is Status.RUNNING
+                       for r in self.scheduler.running.values()):
+                    self._dispatch("decode", self._decode)
+                    finished += self._finish_done()
+            finished += self._drain_failed()
+            return finished
 
     def _dispatch(self, site: str, fn, *args):
         """Run a prefill/decode dispatch with transient-fault retries.
@@ -365,11 +377,13 @@ class Engine:
     def _drain_failed(self) -> List[Request]:
         """Collect requests failed mid-step (deadline, starvation, NaN
         guard) so ``step`` reports every terminal transition it caused."""
-        ev, self.scheduler.failed_events = self.scheduler.failed_events, []
-        now = time.perf_counter()
-        for r in ev:
-            r.metrics.setdefault("t_done", now)
-        return ev
+        with TraceAnnotation("engine.sched.finish"):
+            ev, self.scheduler.failed_events = (
+                self.scheduler.failed_events, [])
+            now = time.perf_counter()
+            for r in ev:
+                r.metrics.setdefault("t_done", now)
+            return ev
 
     # ------------------------------------------------------------------
     def cancel_request(self, rid: int) -> bool:
@@ -415,6 +429,12 @@ class Engine:
             "prefix_misses": pc.misses if pc else 0,
             "prefix_hit_tokens": pc.hit_tokens if pc else 0,
             "prefix_evicted_pages": pc.evicted_pages if pc else 0,
+            # where a request's time to first token goes: queued for a
+            # slot (first admissions), then admission -> first token
+            "admitted": s.admitted,
+            "queue_wait_ns": s.queue_wait_ns,
+            "first_tokens": self.stats["first_tokens"],
+            "prefill_ns": self.stats["prefill_ns"],
         }
 
     # ------------------------------------------------------------------
@@ -433,21 +453,23 @@ class Engine:
         in place, so extra host-side pages never carry live data.  Mixed
         dense/windowed patterns get a full-width table and no exemption.)
         """
-        t = np.full((self.max_slots, 1, self.pages_per_seq), -1, np.int32)
-        windowed = self._ring_tables
-        for slot, req in self.scheduler.running.items():
-            if decode and req.status is not Status.RUNNING:
-                continue
-            row = self.mgr.tables.get(req.rid, [])
-            if len(row) > self.pages_per_seq and not windowed:
-                raise SchedulerInvariantError(
-                    f"request {req.rid} holds {len(row)} pages but the "
-                    f"device block table is {self.pages_per_seq} pages wide "
-                    f"(max_seq_len={self.max_seq_len}); the sequence "
-                    f"outgrew the engine — refusing to truncate its KV "
-                    f"tail silently")
-            t[slot, 0, :len(row)] = row[:self.pages_per_seq]
-        return jnp.asarray(t)
+        with TraceAnnotation("engine.tables"):
+            t = np.full((self.max_slots, 1, self.pages_per_seq), -1,
+                        np.int32)
+            windowed = self._ring_tables
+            for slot, req in self.scheduler.running.items():
+                if decode and req.status is not Status.RUNNING:
+                    continue
+                row = self.mgr.tables.get(req.rid, [])
+                if len(row) > self.pages_per_seq and not windowed:
+                    raise SchedulerInvariantError(
+                        f"request {req.rid} holds {len(row)} pages but the "
+                        f"device block table is {self.pages_per_seq} pages "
+                        f"wide (max_seq_len={self.max_seq_len}); the "
+                        f"sequence outgrew the engine — refusing to "
+                        f"truncate its KV tail silently")
+                t[slot, 0, :len(row)] = row[:self.pages_per_seq]
+            return jnp.asarray(t)
 
     def _prefill(self, admitted: List[Tuple[int, Request]]) -> None:
         """Prefill newly admitted requests (sub-batch padded to max len)."""
@@ -460,50 +482,55 @@ class Engine:
             # suffix only (cold rows are just q_start=0)
             self._prefill_from(slots, reqs)
             return
-        toks = [r.prompt + r.output for r in reqs]  # preempted: re-prefill all
-        L = max(len(t) for t in toks)
-        B = len(reqs)
-        batch = np.zeros((B, L), np.int32)
-        lens = np.zeros((B,), np.int32)
-        for i, t in enumerate(toks):
-            batch[i, :len(t)] = t
-            lens[i] = len(t)
+        with TraceAnnotation("engine.prefill.prep"):
+            # preempted requests re-prefill prompt + generated so far
+            toks = [r.prompt + r.output for r in reqs]
+            L = max(len(t) for t in toks)
+            B = len(reqs)
+            batch = np.zeros((B, L), np.int32)
+            lens = np.zeros((B,), np.int32)
+            for i, t in enumerate(toks):
+                batch[i, :len(t)] = t
+                lens[i] = len(t)
 
-        # sub-batch tables for the admitted slots
-        full_tables = self._tables_array()
-        sub_tables = full_tables[np.asarray(slots), 0]
+            # sub-batch tables for the admitted slots
+            full_tables = self._tables_array()
+            sub_tables = full_tables[np.asarray(slots), 0]
 
-        st = self.state
-        sub_state: Dict[str, Any] = {"pos": jnp.asarray(lens)}
-        if self.paged and "k_pages" in st:
-            sub_state["k_pages"] = st["k_pages"]
-            sub_state["v_pages"] = st["v_pages"]
-            sub_state["tables"] = sub_tables
-        extra = self._collect_extra(reqs)
+            st = self.state
+            sub_state: Dict[str, Any] = {"pos": jnp.asarray(lens)}
+            if self.paged and "k_pages" in st:
+                sub_state["k_pages"] = st["k_pages"]
+                sub_state["v_pages"] = st["v_pages"]
+                sub_state["tables"] = sub_tables
+            extra = self._collect_extra(reqs)
         if not self.paged:
             self._prefill_contiguous(slots, batch, lens, extra, reqs)
             return
 
-        logits, new_st = self.model.prefill(
-            self.params, jnp.asarray(batch), sub_state,
-            lens=jnp.asarray(lens), extra=extra, impl=self.impl)
+        with TraceAnnotation("engine.prefill.model"):
+            logits, new_st = self.model.prefill(
+                self.params, jnp.asarray(batch), sub_state,
+                lens=jnp.asarray(lens), extra=extra, impl=self.impl)
 
         # merge: global pools were written in place (scatter by tables);
         # per-slot states (pos, cross, rec) land in the admitted slots.
-        if "k_pages" in new_st:
-            st["k_pages"] = new_st["k_pages"]
-            st["v_pages"] = new_st["v_pages"]
-        idx = jnp.asarray(slots)
-        st["pos"] = st["pos"].at[idx].set(jnp.asarray(lens))
-        for key in ("cross_k", "cross_v"):
-            if key in new_st:
-                st[key] = st[key].at[:, idx].set(new_st[key])
-        if "rec" in new_st:
-            st["rec"] = jax.tree_util.tree_map(
-                lambda g, s: g.at[:, idx].set(s), st["rec"], new_st["rec"])
+        with TraceAnnotation("engine.prefill.merge"):
+            if "k_pages" in new_st:
+                st["k_pages"] = new_st["k_pages"]
+                st["v_pages"] = new_st["v_pages"]
+            idx = jnp.asarray(slots)
+            st["pos"] = st["pos"].at[idx].set(jnp.asarray(lens))
+            for key in ("cross_k", "cross_v"):
+                if key in new_st:
+                    st[key] = st[key].at[:, idx].set(new_st[key])
+            if "rec" in new_st:
+                st["rec"] = jax.tree_util.tree_map(
+                    lambda g, s: g.at[:, idx].set(s), st["rec"],
+                    new_st["rec"])
 
-        for i, r in enumerate(reqs):
-            r.prefill_pos = int(lens[i])  # everything written
+            for i, r in enumerate(reqs):
+                r.prefill_pos = int(lens[i])  # everything written
         self._cache_insert_live(reqs)
         self._sample_and_append(reqs, logits, first=True)
 
@@ -519,38 +546,41 @@ class Engine:
         Only reachable with the prefix cache on, which gates the model to
         pure dense self-attention — no cross/rec state to merge here.
         """
-        toks = [r.prompt + r.output for r in reqs]
-        starts = np.asarray([r.prefill_pos for r in reqs], np.int32)
-        lens = np.asarray([len(t) for t in toks], np.int32)
-        q_lens = lens - starts  # >= 1: attach caps the match at total-1
-        B, C = len(reqs), int(q_lens.max())
-        batch = np.zeros((B, C), np.int32)
-        for i, t in enumerate(toks):
-            batch[i, :q_lens[i]] = t[starts[i]:lens[i]]
+        with TraceAnnotation("engine.prefill.prep"):
+            toks = [r.prompt + r.output for r in reqs]
+            starts = np.asarray([r.prefill_pos for r in reqs], np.int32)
+            lens = np.asarray([len(t) for t in toks], np.int32)
+            q_lens = lens - starts  # >= 1: attach caps the match at total-1
+            B, C = len(reqs), int(q_lens.max())
+            batch = np.zeros((B, C), np.int32)
+            for i, t in enumerate(toks):
+                batch[i, :q_lens[i]] = t[starts[i]:lens[i]]
 
-        full_tables = self._tables_array()
-        sub_tables = np.asarray(full_tables)[np.asarray(slots)]
-        st = self.state
-        sub_state: Dict[str, Any] = {
-            "pos": jnp.asarray(starts),
-            "k_pages": st["k_pages"],
-            "v_pages": st["v_pages"],
-            "tables": jnp.asarray(sub_tables),
-        }
-        logits, new_st = self.model.prefill_chunk(
-            self.params, jnp.asarray(batch), sub_state,
-            q_start=jnp.asarray(starts), q_lens=jnp.asarray(q_lens),
-            impl=self.impl, interpret=self.interpret,
-            pages_per_block=self.pages_per_block,
-            num_splits=self.num_splits, combine_mode=self.combine_mode,
-            backend=self.backend)
+            full_tables = self._tables_array()
+            sub_tables = np.asarray(full_tables)[np.asarray(slots)]
+            st = self.state
+            sub_state: Dict[str, Any] = {
+                "pos": jnp.asarray(starts),
+                "k_pages": st["k_pages"],
+                "v_pages": st["v_pages"],
+                "tables": jnp.asarray(sub_tables),
+            }
+        with TraceAnnotation("engine.prefill.model"):
+            logits, new_st = self.model.prefill_chunk(
+                self.params, jnp.asarray(batch), sub_state,
+                q_start=jnp.asarray(starts), q_lens=jnp.asarray(q_lens),
+                impl=self.impl, interpret=self.interpret,
+                pages_per_block=self.pages_per_block,
+                num_splits=self.num_splits, combine_mode=self.combine_mode,
+                backend=self.backend)
 
-        st["k_pages"] = new_st["k_pages"]
-        st["v_pages"] = new_st["v_pages"]
-        idx = jnp.asarray(slots)
-        st["pos"] = st["pos"].at[idx].set(jnp.asarray(lens))
-        for i, r in enumerate(reqs):
-            r.prefill_pos = int(lens[i])
+        with TraceAnnotation("engine.prefill.merge"):
+            st["k_pages"] = new_st["k_pages"]
+            st["v_pages"] = new_st["v_pages"]
+            idx = jnp.asarray(slots)
+            st["pos"] = st["pos"].at[idx].set(jnp.asarray(lens))
+            for i, r in enumerate(reqs):
+                r.prefill_pos = int(lens[i])
         self._cache_insert_live(reqs)
         self._sample_and_append(reqs, logits, first=True)
 
@@ -562,11 +592,12 @@ class Engine:
         first — partial pages are skipped inside ``insert``."""
         if self.prefix_cache is None:
             return
-        for r in reqs:
-            row = self.mgr.tables.get(r.rid)
-            if row:
-                self.prefix_cache.insert(r.prompt + r.output, row,
-                                         r.prefill_pos)
+        with TraceAnnotation("engine.prefix.insert"):
+            for r in reqs:
+                row = self.mgr.tables.get(r.rid)
+                if row:
+                    self.prefix_cache.insert(r.prompt + r.output, row,
+                                             r.prefill_pos)
 
     def _prefill_chunk_step(self) -> None:
         """Advance every PREFILLING request by one ``prefill_chunk``
@@ -587,98 +618,107 @@ class Engine:
         chunk = self.prefill_chunk
         budget = chunk  # global per-step token budget, split across rows
         sel: List[Tuple[int, Request, int, int]] = []
-        for slot in sorted(self.scheduler.running):
-            if budget <= 0:
-                break
-            # re-fetch per iteration: grow_prefill below may preempt a
-            # PREFILLING victim in a slot this (snapshotted) loop has not
-            # visited yet — indexing the snapshot would KeyError
-            req = self.scheduler.running.get(slot)
-            if req is None or req.status is not Status.PREFILLING:
-                continue
-            want = min(budget, req.total_len - req.prefill_pos)
-            if not self.scheduler.grow_prefill(req, want):
-                continue  # stalled: keeps pages, resumes next step
-            start = req.prefill_pos
-            q_len = min(want, req.total_len - start)
-            sel.append((slot, req, start, q_len))
-            budget -= q_len
-        # grow_prefill may preempt victims already selected — drop them
-        sel = [(s, r, st0, ql) for (s, r, st0, ql) in sel
-               if self.scheduler.running.get(s) is r]
+        with TraceAnnotation("engine.sched.extend"):
+            for slot in sorted(self.scheduler.running):
+                if budget <= 0:
+                    break
+                # re-fetch per iteration: grow_prefill below may preempt a
+                # PREFILLING victim in a slot this (snapshotted) loop has
+                # not visited yet — indexing the snapshot would KeyError
+                req = self.scheduler.running.get(slot)
+                if req is None or req.status is not Status.PREFILLING:
+                    continue
+                want = min(budget, req.total_len - req.prefill_pos)
+                if not self.scheduler.grow_prefill(req, want):
+                    continue  # stalled: keeps pages, resumes next step
+                start = req.prefill_pos
+                q_len = min(want, req.total_len - start)
+                sel.append((slot, req, start, q_len))
+                budget -= q_len
+            # grow_prefill may preempt victims already selected — drop them
+            sel = [(s, r, st0, ql) for (s, r, st0, ql) in sel
+                   if self.scheduler.running.get(s) is r]
         if not sel:
             return
-        # fixed (max_slots, prefill_chunk) sub-batch shape: padding rows
-        # are dead (tables -1, q_lens 0) so every chunk step traces the
-        # same shapes — no per-shape eager-compile stalls on the serving
-        # hot path from ragged final chunks or varying batch occupancy
-        C = chunk
-        B = self.max_slots
-        batch = np.zeros((B, C), np.int32)
-        q_lens = np.zeros((B,), np.int32)
-        starts = np.zeros((B,), np.int32)
-        slots = [s for s, _, _, _ in sel]
-        reqs = [r for _, r, _, _ in sel]
-        for i, (_, req, st0, ql) in enumerate(sel):
-            seq = req.prompt + req.output
-            batch[i, :ql] = seq[st0:st0 + ql]
-            starts[i] = st0
-            q_lens[i] = ql
-        # padding rows pose as resumes (q_start=1, q_lens=0): they are
-        # dead either way, but must not look like first chunks — a row at
-        # chunk 0 forces the model to recompute cross-attention K/V
-        starts[len(sel):] = 1
+        with TraceAnnotation("engine.prefill.prep"):
+            # fixed (max_slots, prefill_chunk) sub-batch shape: padding
+            # rows are dead (tables -1, q_lens 0) so every chunk step
+            # traces the same shapes — no per-shape eager-compile stalls
+            # on the serving hot path from ragged final chunks or varying
+            # batch occupancy
+            C = chunk
+            B = self.max_slots
+            batch = np.zeros((B, C), np.int32)
+            q_lens = np.zeros((B,), np.int32)
+            starts = np.zeros((B,), np.int32)
+            slots = [s for s, _, _, _ in sel]
+            reqs = [r for _, r, _, _ in sel]
+            for i, (_, req, st0, ql) in enumerate(sel):
+                seq = req.prompt + req.output
+                batch[i, :ql] = seq[st0:st0 + ql]
+                starts[i] = st0
+                q_lens[i] = ql
+            # padding rows pose as resumes (q_start=1, q_lens=0): they are
+            # dead either way, but must not look like first chunks — a row
+            # at chunk 0 forces the model to recompute cross-attention K/V
+            starts[len(sel):] = 1
 
-        full_tables = self._tables_array()
-        sub_tables = np.full((B,) + full_tables.shape[1:], -1, np.int32)
-        sub_tables[:len(slots)] = np.asarray(full_tables)[np.asarray(slots)]
+            full_tables = self._tables_array()
+            sub_tables = np.full((B,) + full_tables.shape[1:], -1, np.int32)
+            sub_tables[:len(slots)] = np.asarray(full_tables)[
+                np.asarray(slots)]
 
-        st = self.state
-        sub_state: Dict[str, Any] = {
-            "pos": jnp.asarray(starts),
-            "k_pages": st["k_pages"],
-            "v_pages": st["v_pages"],
-            "tables": jnp.asarray(sub_tables),
-        }
-        for key in ("cross_k", "cross_v"):
-            if key in st:
-                # resume rows reuse their cached cross-K/V (the model
-                # skips the encoder/projection when no row is at chunk 0)
-                sub = np.zeros((st[key].shape[0], B) + st[key].shape[2:],
-                               st[key].dtype)
-                sub[:, :len(slots)] = np.asarray(st[key])[:, np.asarray(slots)]
-                sub_state[key] = jnp.asarray(sub)
-        extra = self._collect_extra(reqs, pad_to=B)
-        logits, new_st = self.model.prefill_chunk(
-            self.params, jnp.asarray(batch), sub_state,
-            q_start=jnp.asarray(starts), q_lens=jnp.asarray(q_lens),
-            extra=extra, impl=self.impl, interpret=self.interpret,
-            pages_per_block=self.pages_per_block,
-            num_splits=self.num_splits, combine_mode=self.combine_mode,
-            backend=self.backend)
+            st = self.state
+            sub_state: Dict[str, Any] = {
+                "pos": jnp.asarray(starts),
+                "k_pages": st["k_pages"],
+                "v_pages": st["v_pages"],
+                "tables": jnp.asarray(sub_tables),
+            }
+            for key in ("cross_k", "cross_v"):
+                if key in st:
+                    # resume rows reuse their cached cross-K/V (the model
+                    # skips the encoder/projection when no row is at
+                    # chunk 0)
+                    sub = np.zeros(
+                        (st[key].shape[0], B) + st[key].shape[2:],
+                        st[key].dtype)
+                    sub[:, :len(slots)] = np.asarray(st[key])[
+                        :, np.asarray(slots)]
+                    sub_state[key] = jnp.asarray(sub)
+            extra = self._collect_extra(reqs, pad_to=B)
+        with TraceAnnotation("engine.prefill.model"):
+            logits, new_st = self.model.prefill_chunk(
+                self.params, jnp.asarray(batch), sub_state,
+                q_start=jnp.asarray(starts), q_lens=jnp.asarray(q_lens),
+                extra=extra, impl=self.impl, interpret=self.interpret,
+                pages_per_block=self.pages_per_block,
+                num_splits=self.num_splits, combine_mode=self.combine_mode,
+                backend=self.backend)
 
-        st["k_pages"] = new_st["k_pages"]
-        st["v_pages"] = new_st["v_pages"]
-        idx = jnp.asarray(slots)
-        live = np.arange(len(slots))
-        st["pos"] = st["pos"].at[idx].set(
-            jnp.asarray((starts + q_lens)[live]))
-        for key in ("cross_k", "cross_v"):
-            if key in new_st:
-                st[key] = st[key].at[:, idx].set(new_st[key][:, live])
+        with TraceAnnotation("engine.prefill.merge"):
+            st["k_pages"] = new_st["k_pages"]
+            st["v_pages"] = new_st["v_pages"]
+            idx = jnp.asarray(slots)
+            live = np.arange(len(slots))
+            st["pos"] = st["pos"].at[idx].set(
+                jnp.asarray((starts + q_lens)[live]))
+            for key in ("cross_k", "cross_v"):
+                if key in new_st:
+                    st[key] = st[key].at[:, idx].set(new_st[key][:, live])
 
-        done_rows, done_reqs = [], []
-        for i, (_, req, st0, ql) in enumerate(sel):
-            req.prefill_pos = st0 + ql
-            if req.prefill_pos >= req.total_len:  # last chunk landed
-                req.status = Status.RUNNING
-                done_rows.append(i)
-                done_reqs.append(req)
-        self._cache_insert_live([r for _, r, _, _ in sel])
+            done_rows, done_reqs = [], []
+            for i, (_, req, st0, ql) in enumerate(sel):
+                req.prefill_pos = st0 + ql
+                if req.prefill_pos >= req.total_len:  # last chunk landed
+                    req.status = Status.RUNNING
+                    done_rows.append(i)
+                    done_reqs.append(req)
+            if done_reqs:
+                done_logits = jnp.asarray(logits)[np.asarray(done_rows)]
+        self._cache_insert_live(reqs)
         if done_reqs:
-            self._sample_and_append(
-                done_reqs, jnp.asarray(logits)[np.asarray(done_rows)],
-                first=True)
+            self._sample_and_append(done_reqs, done_logits, first=True)
 
     def _prefill_contiguous(self, slots, batch, lens, extra, reqs):
         """Baseline prefill: run forward, copy K/V into max-length buffers."""
@@ -698,24 +738,27 @@ class Engine:
             "v_pages": jnp.zeros((n_attn, B * pp, Hkv, ps, hd), self.dtype),
             "tables": tmp_tables,
         }
-        logits, new_st = self.model.prefill(
-            self.params, jnp.asarray(batch), tmp_state,
-            lens=jnp.asarray(lens), extra=extra, impl=self.impl)
+        with TraceAnnotation("engine.prefill.model"):
+            logits, new_st = self.model.prefill(
+                self.params, jnp.asarray(batch), tmp_state,
+                lens=jnp.asarray(lens), extra=extra, impl=self.impl)
         from repro.core.cache import gather_layer
-        idx = jnp.asarray(slots)
-        st = self.state
-        for li in range(n_attn):
-            k, v = gather_layer(new_st["k_pages"][li], new_st["v_pages"][li],
-                                tmp_tables, L)
-            st["k_buf"] = st["k_buf"].at[li, idx, :L].set(k)
-            st["v_buf"] = st["v_buf"].at[li, idx, :L].set(v)
-        st["pos"] = st["pos"].at[idx].set(jnp.asarray(lens))
-        for key in ("cross_k", "cross_v"):
-            if key in new_st:
-                st[key] = st[key].at[:, idx].set(new_st[key])
-        if "rec" in new_st:
-            st["rec"] = jax.tree_util.tree_map(
-                lambda g, s: g.at[:, idx].set(s), st["rec"], new_st["rec"])
+        with TraceAnnotation("engine.prefill.merge"):
+            idx = jnp.asarray(slots)
+            st = self.state
+            for li in range(n_attn):
+                k, v = gather_layer(new_st["k_pages"][li],
+                                    new_st["v_pages"][li], tmp_tables, L)
+                st["k_buf"] = st["k_buf"].at[li, idx, :L].set(k)
+                st["v_buf"] = st["v_buf"].at[li, idx, :L].set(v)
+            st["pos"] = st["pos"].at[idx].set(jnp.asarray(lens))
+            for key in ("cross_k", "cross_v"):
+                if key in new_st:
+                    st[key] = st[key].at[:, idx].set(new_st[key])
+            if "rec" in new_st:
+                st["rec"] = jax.tree_util.tree_map(
+                    lambda g, s: g.at[:, idx].set(s), st["rec"],
+                    new_st["rec"])
         self._sample_and_append(reqs, logits, first=True)
 
     def _collect_extra(self, reqs: List[Request],
@@ -746,35 +789,40 @@ class Engine:
             combine_mode=self.combine_mode, backend=self.backend)
 
     def _decode(self) -> None:
-        st = dict(self.state)
-        if self.paged and "k_pages" in st:
-            # decode=True blanks PREFILLING slots: their pages must not
-            # receive the placeholder token's K/V nor be attended over
-            st["tables"] = self._tables_array(decode=True)
-        tokens = np.zeros((self.max_slots,), np.int32)
-        live = np.zeros((self.max_slots,), bool)
-        reqs: List[Optional[Request]] = [None] * self.max_slots
-        for slot, req in self.scheduler.running.items():
-            if req.status is not Status.RUNNING:
-                continue  # mid-prefill: not in the decode sub-batch
-            seq = req.prompt + req.output
-            tokens[slot] = seq[-1]
-            live[slot] = True
-            reqs[slot] = req
+        with TraceAnnotation("engine.decode.prep"):
+            st = dict(self.state)
+            if self.paged and "k_pages" in st:
+                # decode=True blanks PREFILLING slots: their pages must not
+                # receive the placeholder token's K/V nor be attended over
+                st["tables"] = self._tables_array(decode=True)
+            tokens = np.zeros((self.max_slots,), np.int32)
+            live = np.zeros((self.max_slots,), bool)
+            reqs: List[Optional[Request]] = [None] * self.max_slots
+            for slot, req in self.scheduler.running.items():
+                if req.status is not Status.RUNNING:
+                    continue  # mid-prefill: not in the decode sub-batch
+                seq = req.prompt + req.output
+                tokens[slot] = seq[-1]
+                live[slot] = True
+                reqs[slot] = req
 
-        if self.paged or "k_buf" not in st:
-            logits, new_st = self._jit_decode(self.params,
-                                              jnp.asarray(tokens), st)
-        else:
-            logits, new_st = self._decode_contiguous(jnp.asarray(tokens), st)
-        # dead slots keep their old pos (decode bumps everyone's)
-        mask = jnp.asarray(live)
-        new_st["pos"] = jnp.where(mask, new_st["pos"], self.state["pos"])
-        if self.paged and "tables" in new_st:
-            new_st.pop("tables")  # host-owned, rebuilt each step
-        self.state.update(new_st)
-        live_reqs = [r for r in reqs if r is not None]
-        live_logits = jnp.asarray(logits)[np.where(live)[0]]
+        with TraceAnnotation("engine.decode.launch"):
+            if self.paged or "k_buf" not in st:
+                logits, new_st = self._jit_decode(self.params,
+                                                  jnp.asarray(tokens), st)
+            else:
+                logits, new_st = self._decode_contiguous(
+                    jnp.asarray(tokens), st)
+        with TraceAnnotation("engine.decode.merge"):
+            # dead slots keep their old pos (decode bumps everyone's)
+            mask = jnp.asarray(live)
+            new_st["pos"] = jnp.where(mask, new_st["pos"],
+                                      self.state["pos"])
+            if self.paged and "tables" in new_st:
+                new_st.pop("tables")  # host-owned, rebuilt each step
+            self.state.update(new_st)
+            live_reqs = [r for r in reqs if r is not None]
+            live_logits = jnp.asarray(logits)[np.where(live)[0]]
         self._sample_and_append(live_reqs, live_logits, first=False)
 
     def _decode_contiguous(self, tokens, st):
@@ -807,61 +855,69 @@ class Engine:
 
     def _sample_and_append(self, reqs: List[Request], logits: jnp.ndarray,
                            first: bool) -> None:
-        logits = jnp.asarray(logits)
-        if self.faults is not None and reqs:
-            # injected NaN logits: per-row poison, caught by the guard
-            bad = [i for i, r in enumerate(reqs)
-                   if self.faults.fire("sample", rid=r.rid) == "nan"]
-            if bad:
-                logits = logits.at[jnp.asarray(bad)].set(jnp.nan)
-        if self.numerics_guard and reqs:
-            # per-row isolation: a poisoned row (overflowed activations,
-            # injected NaN) fails *its* request; survivors sample as if
-            # the bad row never existed (their logits depend only on
-            # their own KV pages, so outputs are bit-identical — gated
-            # by tests/test_faults.py)
-            finite = np.asarray(jnp.all(jnp.isfinite(logits), axis=-1))
-            if not finite.all():
-                for r, ok in zip(reqs, finite):
-                    if not ok:
-                        self.scheduler.fail(r, NumericsError(
-                            "non-finite logits in this request's row "
-                            f"(step {self.steps})", rid=r.rid,
-                            step=self.steps))
-                keep = np.where(finite)[0]
-                reqs = [reqs[i] for i in keep]
-                logits = logits[jnp.asarray(keep)]
-        B = len(reqs)
-        if B == 0:
-            self.rng, _ = jax.random.split(self.rng)  # keep stream parity
-            return
-        sp = SampleParams(
-            temperature=jnp.asarray([r.temperature for r in reqs], jnp.float32),
-            top_k=jnp.asarray([r.top_k for r in reqs], jnp.int32),
-            top_p=jnp.asarray([r.top_p for r in reqs], jnp.float32),
-        )
-        self.rng, key = jax.random.split(self.rng)
-        toks = np.asarray(sample(key, logits, sp))
-        now = time.perf_counter()
-        for r, t in zip(reqs, toks):
-            r.output.append(int(t))
-            if first and "ttft_s" not in r.metrics:
-                r.metrics["ttft_s"] = now - r.metrics["t_arrive"]
+        with TraceAnnotation("engine.sample.guard"):
+            logits = jnp.asarray(logits)
+            if self.faults is not None and reqs:
+                # injected NaN logits: per-row poison, caught by the guard
+                bad = [i for i, r in enumerate(reqs)
+                       if self.faults.fire("sample", rid=r.rid) == "nan"]
+                if bad:
+                    logits = logits.at[jnp.asarray(bad)].set(jnp.nan)
+            if self.numerics_guard and reqs:
+                # per-row isolation: a poisoned row (overflowed
+                # activations, injected NaN) fails *its* request;
+                # survivors sample as if the bad row never existed (their
+                # logits depend only on their own KV pages, so outputs are
+                # bit-identical — gated by tests/test_faults.py)
+                finite = np.asarray(jnp.all(jnp.isfinite(logits), axis=-1))
+                if not finite.all():
+                    for r, ok in zip(reqs, finite):
+                        if not ok:
+                            self.scheduler.fail(r, NumericsError(
+                                "non-finite logits in this request's row "
+                                f"(step {self.steps})", rid=r.rid,
+                                step=self.steps))
+                    keep = np.where(finite)[0]
+                    reqs = [reqs[i] for i in keep]
+                    logits = logits[jnp.asarray(keep)]
+        with TraceAnnotation("engine.sample.draw"):
+            if not reqs:
+                self.rng, _ = jax.random.split(self.rng)  # stream parity
+                return
+            sp = SampleParams(
+                temperature=jnp.asarray([r.temperature for r in reqs],
+                                        jnp.float32),
+                top_k=jnp.asarray([r.top_k for r in reqs], jnp.int32),
+                top_p=jnp.asarray([r.top_p for r in reqs], jnp.float32),
+            )
+            self.rng, key = jax.random.split(self.rng)
+            toks = np.asarray(sample(key, logits, sp))
+        with TraceAnnotation("engine.sample.append"):
+            now = time.perf_counter()
+            for r, t in zip(reqs, toks):
+                r.output.append(int(t))
+                if first and "ttft_s" not in r.metrics:
+                    r.metrics["ttft_s"] = now - r.metrics["t_arrive"]
+                    self.stats["first_tokens"] += 1
+                    self.stats["prefill_ns"] += round(
+                        (now - r.metrics["t_admit"]) * 1e9)
 
     def _finish_done(self) -> List[Request]:
-        done = []
-        for req in list(self.scheduler.running.values()):
-            if req.status is not Status.RUNNING:
-                continue  # mid-prefill requests have no fresh sample
-            hit_eos = (req.eos_id is not None and req.output
-                       and req.output[-1] == req.eos_id)
-            if len(req.output) >= req.max_new_tokens or hit_eos:
-                req.metrics["t_done"] = time.perf_counter()
-                req.metrics["tok_s"] = len(req.output) / max(
-                    req.metrics["t_done"] - req.metrics["t_arrive"], 1e-9)
-                self.scheduler.finish(req)
-                done.append(req)
-        return done
+        with TraceAnnotation("engine.sched.finish"):
+            done = []
+            for req in list(self.scheduler.running.values()):
+                if req.status is not Status.RUNNING:
+                    continue  # mid-prefill requests have no fresh sample
+                hit_eos = (req.eos_id is not None and req.output
+                           and req.output[-1] == req.eos_id)
+                if len(req.output) >= req.max_new_tokens or hit_eos:
+                    req.metrics["t_done"] = time.perf_counter()
+                    req.metrics["tok_s"] = len(req.output) / max(
+                        req.metrics["t_done"] - req.metrics["t_arrive"],
+                        1e-9)
+                    self.scheduler.finish(req)
+                    done.append(req)
+            return done
 
     # ------------------------------------------------------------------
     # prefix sharing (paper §III contribution 1: fork + copy-on-write)

@@ -32,6 +32,7 @@ Policy (vLLM-style):
 
 from __future__ import annotations
 
+import time
 from typing import Dict, List, Optional, Tuple
 
 from repro.core.paging import HostPageManager
@@ -81,6 +82,11 @@ class Scheduler:
         self.cancelled: int = 0
         self.deadline_misses: int = 0
         self.failed_events: List[Request] = []
+        # first admissions, and the sum of their time queued since
+        # ``t_arrive`` (ns); a re-admission after preemption counts in
+        # neither (``preempted`` counts it)
+        self.admitted: int = 0
+        self.queue_wait_ns: int = 0
 
     # ------------------------------------------------------------------
     def add(self, req: Request) -> None:
@@ -180,6 +186,14 @@ class Scheduler:
                     self.mgr.free(req.rid)
                 break  # head-of-line blocking keeps FIFO fairness
             self.waiting.pop(0)
+            if "t_admit" not in req.metrics:
+                now = time.perf_counter()
+                req.metrics["t_admit"] = now
+                self.admitted += 1
+                # a request queued here directly, not through
+                # Engine.add_request, has no arrival stamp: no wait
+                self.queue_wait_ns += round(
+                    (now - req.metrics.get("t_arrive", now)) * 1e9)
             slot = slots.pop(0)
             req.prefill_pos = matched
             req.cached_prefix = matched

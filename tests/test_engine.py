@@ -297,3 +297,23 @@ def test_generate_raises_when_max_steps_leaves_requests_unfinished():
     with pytest.raises(DeadlineExceeded, match="1 of 1 requests unfinished"):
         eng.generate(reqs, max_steps=3)
     assert not reqs[0].done
+
+
+@pytest.mark.parametrize("chunk,pool", [(None, 128), (16, 64)],
+                         ids=["monolithic", "chunked"])
+def test_ttft_counters_split_queue_wait_from_prefill(chunk, pool):
+    """Under preemption each request counts one admission and one first
+    token; ``queue_wait_ns`` and ``prefill_ns`` are the sums of arrival ->
+    first admission and first admission -> first token."""
+    eng = make_engine(pool_tokens=pool, prefill_chunk=chunk)
+    reqs = [Request(prompt=[1] * 40, max_new_tokens=8) for _ in range(6)]
+    eng.generate(reqs, max_steps=400)
+    rep = eng.robustness_report()
+    assert rep["preempted"] >= 1
+    assert rep["admitted"] == rep["first_tokens"] == len(reqs)
+    m = [r.metrics for r in reqs]
+    assert rep["queue_wait_ns"] == pytest.approx(
+        sum(x["t_admit"] - x["t_arrive"] for x in m) * 1e9, abs=len(m))
+    assert rep["prefill_ns"] == pytest.approx(
+        sum(x["t_arrive"] + x["ttft_s"] - x["t_admit"] for x in m) * 1e9,
+        abs=len(m) * 10)

@@ -454,3 +454,37 @@ def test_cascaded_preemption_keeps_invariants():
     # exactly one survivor decodes on
     assert len(sched.running) == 1
     check_allocator_invariants(mgr, sched)
+
+
+def test_admission_counters_count_first_admissions(monkeypatch):
+    """``queue_wait_ns`` sums each request's wait from ``t_arrive`` to its
+    first admission, however many steps it waited; a preempted request's
+    re-admission adds to neither ``admitted`` nor ``queue_wait_ns``."""
+    import repro.serving.scheduler as scheduler_mod
+    clock = [0.0]
+    monkeypatch.setattr(scheduler_mod.time, "perf_counter",
+                        lambda: clock[0])
+    mgr = HostPageManager(num_pages=32, page_size=4)
+    sched = Scheduler(mgr, max_slots=2, max_seq_len=64, headroom_pages=1)
+    r0, r1, r2 = (Request(prompt=[1] * 8, max_new_tokens=8)
+                  for _ in range(3))
+    for r in (r0, r1, r2):
+        r.metrics["t_arrive"] = 0.0
+        sched.add(r)
+    clock[0] = 1.0
+    assert [r for _, r in sched.admit()] == [r0, r1]
+    assert (sched.admitted, sched.queue_wait_ns) == (2, 2_000_000_000)
+    for _ in range(3):  # r2 waits three steps for a slot
+        clock[0] += 1.0
+        assert sched.admit() == []
+    sched.finish(r0)
+    clock[0] = 5.0
+    assert [r for _, r in sched.admit()] == [r2]
+    assert (sched.admitted, sched.queue_wait_ns) == (3, 7_000_000_000)
+    sched._preempt(r1)
+    clock[0] = 6.0
+    assert [r for _, r in sched.admit()] == [r1]
+    assert sched.preempted == 1
+    assert (sched.admitted, sched.queue_wait_ns) == (3, 7_000_000_000)
+    assert r1.metrics["t_admit"] == 1.0
+    check_allocator_invariants(mgr, sched)

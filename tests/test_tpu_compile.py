@@ -10,6 +10,9 @@ library.
 """
 
 import functools
+import importlib.util
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +21,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.core.attention import prefill_attention
+from repro.kernels.paged_attention import ops
 from repro.kernels.paged_attention.paged_attention import (
     combine_partials_pallas, paged_attention_kernel, paged_prefill_kernel)
 from repro.models.api import build_model
@@ -25,6 +29,21 @@ from repro.serving import Engine
 
 B, N_KV, G, D, PAGE = 8, 8, 4, 128, 64
 NUM_PAGES, MAX_PAGES = 256, 32  # 16k-token pool, 2048-token tables
+DEVTRACE = Path(__file__).resolve().parents[1] / "bench" / "devtrace.py"
+
+
+def kernel_names(text: str) -> set:
+    """The Pallas calls of a compiled program, each named as the
+    benchmark's trace reduction (``bench/devtrace.py``) names its op: the
+    roofline metrics find the decode and prefill kernels by these names."""
+    if "bench_devtrace" not in sys.modules:
+        spec = importlib.util.spec_from_file_location("bench_devtrace",
+                                                      DEVTRACE)
+        sys.modules[spec.name] = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(sys.modules[spec.name])
+    instruction = sys.modules["bench_devtrace"].instruction
+    return {instruction(line.strip()) for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line}
 
 
 @pytest.fixture(scope="module")
@@ -82,6 +101,17 @@ def test_paged_prefill_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
+def test_paged_prefill_op_keeps_its_kernel_name(one_chip):
+    """The chunked prefill op the engine calls compiles to a kernel named
+    ``paged_prefill``, which ``prefill_attn_roofline`` reads."""
+    fn = functools.partial(ops.paged_prefill, interpret=False)
+    text = _compiled_text(
+        fn, one_chip, ((B, 512, N_KV * G, D), jnp.bfloat16), _pool(),
+        _pool(), ((B, MAX_PAGES), jnp.int32), ((B,), jnp.int32),
+        ((B,), jnp.int32))
+    assert "paged_prefill" in kernel_names(text)
+
+
 def test_combine_compiles(one_chip):
     fn = functools.partial(combine_partials_pallas, dtype=jnp.bfloat16,
                            interpret=False)
@@ -119,3 +149,5 @@ def test_engine_decode_step_compiles(one_chip):
         (params, jnp.zeros((B,), jnp.int32), st))
     text = eng._jit_decode.lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    # the name decode_attn_roofline reads the decode kernel's time by
+    assert "paged_attention" in kernel_names(text)
