@@ -15,6 +15,7 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core import attention as core_attn
@@ -253,3 +254,20 @@ def cross_kv(p: Dict, ctx: jax.Array) -> Tuple[jax.Array, jax.Array]:
     k = jnp.einsum("btd,dhk->bthk", ctx, p["wk"])
     v = jnp.einsum("btd,dhk->bthk", ctx, p["wv"])
     return k, v
+
+
+def cross_gate(q_start) -> Dict:
+    """Which rows of a prompt chunk compute fresh cross-attention K/V.
+
+    Only rows at chunk 0 (``q_start == 0``, host ints) need them; resume
+    rows reuse their cached rows.  Returns the keywords ``prefill_chunk``
+    takes: ``cross_mode`` "full" (every row is at chunk 0), "reuse" (none
+    is) or "partial", with ``first_rows`` the indices of those that are.
+    Decided on the host, so a jitted chunk step stays free of host syncs.
+    """
+    firsts = np.flatnonzero(np.asarray(q_start) == 0).astype(np.int32)
+    if firsts.size == len(q_start):
+        return {"cross_mode": "full"}
+    if firsts.size == 0:
+        return {"cross_mode": "reuse"}
+    return {"cross_mode": "partial", "first_rows": firsts}

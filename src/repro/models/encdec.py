@@ -16,7 +16,6 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.configs.base import ModelConfig, RunConfig
 from repro.models import attention as attn
@@ -190,32 +189,26 @@ class EncDecModel:
                       pages_per_block: Optional[int] = None,
                       num_splits: Optional[int] = None,
                       combine_mode: Optional[str] = None,
-                      backend: Optional[str] = None
+                      backend: Optional[str] = None,
+                      cross_mode: str = "full",
+                      first_rows: Optional[jax.Array] = None
                       ) -> Tuple[jax.Array, Dict]:
         """Chunked decoder prefill (same contract as
         `TransformerModel.prefill_chunk`): the chunk's self-attention
         resumes from the cached prefix pages at ``q_start``.  The audio
         encoder and per-layer cross-attention K/V depend only on the
-        frames, and the gate is **per row**: only rows at chunk 0
-        (``q_start == 0``) run the encoder — their frames are gathered
-        into a smaller encode batch and their fresh cross-K/V scattered
-        into the cached ``state["cross_k"/"cross_v"]`` stack; resume rows
-        never pay the encoder again.  (The former batch-wide gate
-        re-encoded the whole sub-batch whenever *any* row was at chunk 0
-        — idempotent for resume rows, but O(B) encoder work per
-        admission.)  Host-driven (eager) dispatch, hence the concrete
-        numpy indices."""
+        frames, and the gate is **per row** (``cross_mode`` /
+        ``first_rows``, from `attention.cross_gate`): "partial" encodes
+        only the rows at chunk 0 — their frames gathered into a smaller
+        encode batch, their fresh cross-K/V scattered into the cached
+        ``state["cross_k"/"cross_v"]`` stack — so resume rows never pay
+        the encoder again; "full" encodes every row, "reuse" none."""
         cfg = self.cfg
-        B, C = tokens.shape
-        firsts = np.flatnonzero(np.asarray(q_start) == 0)
-        if "cross_k" not in state or firsts.size == B:
-            cross_mode, first_rows = "full", None
+        C = tokens.shape[1]
+        enc = None
+        if cross_mode == "full":
             enc = self.encode(params, extra["frames"], impl)
-        elif firsts.size == 0:
-            cross_mode, first_rows, enc = "reuse", None, None
-        else:
-            cross_mode = "partial"
-            first_rows = jnp.asarray(firsts)
+        elif cross_mode == "partial":
             enc = self.encode(params, extra["frames"][first_rows], impl)
         pos = (q_start[:, None].astype(jnp.int32)
                + jnp.arange(C, dtype=jnp.int32)[None])

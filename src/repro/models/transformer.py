@@ -29,7 +29,6 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.configs.base import ModelConfig, RunConfig
 from repro.distributed.sharding import logical_shard
@@ -343,7 +342,9 @@ class TransformerModel:
                       pages_per_block: Optional[int] = None,
                       num_splits: Optional[int] = None,
                       combine_mode: Optional[str] = None,
-                      backend: Optional[str] = None
+                      backend: Optional[str] = None,
+                      cross_mode: str = "full",
+                      first_rows: Optional[jax.Array] = None
                       ) -> Tuple[jax.Array, Dict]:
         """Chunked prefill: one prompt *chunk* per sequence, resuming from
         the cached prefix.
@@ -358,6 +359,14 @@ class TransformerModel:
         chunk) and the updated state.  ``prefill(tokens, lens)`` is the
         single-chunk special case (``q_start = 0``, ``q_lens = lens``).
 
+        ``cross_mode`` / ``first_rows`` gate the cross-attention K/V
+        (`attention.cross_gate` picks them from the host's ``q_start``):
+        "full" projects every row's image context, "reuse" keeps the
+        cached ``state["cross_k"/"cross_v"]``, "partial" projects only
+        ``first_rows`` and scatters their fresh K/V into the cached stack.
+        Nothing here reads a traced value on the host, so the whole call
+        jits.
+
         Recurrent codes (R/M/S) are not chunkable — their prefill state
         replay assumes the whole prompt; the engine gates them out.
         """
@@ -369,50 +378,33 @@ class TransformerModel:
                 f"(pattern {cfg.layer_pattern!r}): carrying recurrent "
                 "state across chunks is an open ROADMAP item",
                 pattern=cfg.layer_pattern)
-        B, C = tokens.shape
-        # cross-attention K/V depend only on the image context, and only
-        # rows at chunk 0 need them computed — resume rows reuse their
-        # cached state["cross_k"/"cross_v"] rows untouched.  The gate is
-        # *per row*: project just the first-chunk rows' context and
-        # scatter their fresh K/V into the cached stack.  (The former
-        # batch-wide gate re-projected every row whenever any row was at
-        # chunk 0 — idempotent for resume rows, but O(B) vision-encoder
-        # work per admission instead of O(first-chunk rows).)
-        # Host-driven (the engine calls this eagerly), hence the
-        # concrete numpy indices.
-        cross_mode, first_rows, proj = "reuse", None, None
-        if self.n_cross_layers:
-            firsts = np.flatnonzero(np.asarray(q_start) == 0)
-            if "cross_k" not in state or firsts.size == B:
-                cross_mode = "full"
-            elif firsts.size == 0:
-                cross_mode = "reuse"
-            else:
-                cross_mode = "partial"
-                first_rows = jnp.asarray(firsts)
-            if cross_mode != "reuse":
-                sub = extra
-                if cross_mode == "partial":
-                    sub = dict(extra,
-                               image_embeds=extra["image_embeds"][first_rows])
-                proj = self._project_extra(params, sub)
+        proj = None
+        if self.n_cross_layers and cross_mode != "reuse":
+            sub = extra
+            if cross_mode == "partial":
+                sub = dict(extra,
+                           image_embeds=extra["image_embeds"][first_rows])
+            proj = self._project_extra(params, sub)
         x = layers.embed_tokens(params["embed"], tokens)
 
         st = dict(state)
         ai = ci = 0
-        new_k, new_v, new_ck, new_cv = [], [], [], []
+        new_ck, new_cv = [], []
         for code, p in zip(codes, self._per_layer_params(params)):
             h = layers.apply_norm(p["ln1"], x)
             if code in ATTN_CODES:
                 w = cfg.window if code == "W" else 0
+                # each layer's pages go back into the stacked pool in
+                # place: jitted, the program holds one output pool, where
+                # stacking per-layer results held a second in temporaries
                 o, kp, vp = attn.attn_prefill_chunked(
                     p["attn"], h, cfg, st["k_pages"][ai], st["v_pages"][ai],
                     st["tables"], q_start, q_lens, window=w, impl=impl,
                     interpret=interpret, pages_per_block=pages_per_block,
                     num_splits=num_splits, combine_mode=combine_mode,
                     backend=backend)
-                new_k.append(kp)
-                new_v.append(vp)
+                st["k_pages"] = st["k_pages"].at[ai].set(kp)
+                st["v_pages"] = st["v_pages"].at[ai].set(vp)
                 ai += 1
                 x = x + o
             elif code == "C":
@@ -434,9 +426,6 @@ class TransformerModel:
                     p["attn"], h, ck, cv, cfg)
             x, _ = self._apply_ffn(p, x)
 
-        if self.n_attn_layers:
-            st["k_pages"] = jnp.stack(new_k)
-            st["v_pages"] = jnp.stack(new_v)
         if self.n_cross_layers:
             st["cross_k"] = jnp.stack(new_ck)
             st["cross_v"] = jnp.stack(new_cv)

@@ -40,6 +40,7 @@ from repro.errors import (DeadlineExceeded, EngineConfigError,
                           RequestTooLong, SchedulerInvariantError,
                           TransientDeviceError)
 from repro.models.api import build_model
+from repro.models.attention import cross_gate
 from repro.serving.faults import FaultPlan, FaultyPageManager
 from repro.serving.request import Request, Status
 from repro.serving.sampler import SampleParams, sample, validate_sample_params
@@ -194,8 +195,11 @@ class Engine:
         self._slot_extra: Dict[int, Dict] = {}
         self.steps = 0
         self.stats: Dict[str, int] = {"transient_retries": 0,
-                                      "first_tokens": 0, "prefill_ns": 0}
+                                      "first_tokens": 0, "prefill_ns": 0,
+                                      "chunk_steps": 0, "chunk_traces": 0}
         self._jit_decode = jax.jit(self._decode_fn, static_argnames=())
+        self._jit_chunk = jax.jit(self._chunk_fn,
+                                  static_argnames=("cross_mode",))
 
     # ------------------------------------------------------------------
     def _init_state(self) -> Dict:
@@ -435,6 +439,10 @@ class Engine:
             "queue_wait_ns": s.queue_wait_ns,
             "first_tokens": self.stats["first_tokens"],
             "prefill_ns": self.stats["prefill_ns"],
+            # the compiled chunk step: dispatches, and times it was traced
+            # (one per engine, a few more for cross-attention models)
+            "chunk_steps": self.stats["chunk_steps"],
+            "chunk_traces": self.stats["chunk_traces"],
         }
 
     # ------------------------------------------------------------------
@@ -642,10 +650,9 @@ class Engine:
             return
         with TraceAnnotation("engine.prefill.prep"):
             # fixed (max_slots, prefill_chunk) sub-batch shape: padding
-            # rows are dead (tables -1, q_lens 0) so every chunk step
-            # traces the same shapes — no per-shape eager-compile stalls
-            # on the serving hot path from ragged final chunks or varying
-            # batch occupancy
+            # rows are dead (tables -1, q_lens 0) so every chunk step runs
+            # the one compiled program — ragged final chunks and varying
+            # batch occupancy trace nothing new on the serving hot path
             C = chunk
             B = self.max_slots
             batch = np.zeros((B, C), np.int32)
@@ -687,14 +694,14 @@ class Engine:
                         :, np.asarray(slots)]
                     sub_state[key] = jnp.asarray(sub)
             extra = self._collect_extra(reqs, pad_to=B)
+            # the rows' starts are host ints: the cross-attention gate is
+            # decided here and handed to the program (static mode)
+            gate = cross_gate(starts) if "cross_k" in sub_state else {}
         with TraceAnnotation("engine.prefill.model"):
-            logits, new_st = self.model.prefill_chunk(
+            self.stats["chunk_steps"] += 1
+            logits, new_st = self._jit_chunk(
                 self.params, jnp.asarray(batch), sub_state,
-                q_start=jnp.asarray(starts), q_lens=jnp.asarray(q_lens),
-                extra=extra, impl=self.impl, interpret=self.interpret,
-                pages_per_block=self.pages_per_block,
-                num_splits=self.num_splits, combine_mode=self.combine_mode,
-                backend=self.backend)
+                jnp.asarray(starts), jnp.asarray(q_lens), extra, **gate)
 
         with TraceAnnotation("engine.prefill.merge"):
             st["k_pages"] = new_st["k_pages"]
@@ -782,6 +789,18 @@ class Engine:
         return out
 
     # ------------------------------------------------------------------
+    def _chunk_fn(self, params, tokens, state, q_start, q_lens, extra,
+                  cross_mode="full", first_rows=None):
+        """The model part of a chunk step, jitted as ``_jit_chunk``: one
+        program at the fixed ``(max_slots, prefill_chunk)`` shape."""
+        self.stats["chunk_traces"] += 1  # the body runs only when traced
+        return self.model.prefill_chunk(
+            params, tokens, state, q_start=q_start, q_lens=q_lens,
+            extra=extra, cross_mode=cross_mode, first_rows=first_rows,
+            impl=self.impl, interpret=self.interpret,
+            pages_per_block=self.pages_per_block, num_splits=self.num_splits,
+            combine_mode=self.combine_mode, backend=self.backend)
+
     def _decode_fn(self, params, tokens, state):
         return self.model.decode_step(
             params, tokens, state, impl=self.impl, interpret=self.interpret,

@@ -34,6 +34,7 @@ from repro.kernels.paged_attention.ref import (paged_attention_ref,
                                                paged_prefill_partials_ref,
                                                paged_prefill_ref)
 from repro.models.api import build_model
+from repro.models.attention import cross_gate
 from repro.serving import Engine, Request
 from repro.serving.request import Status
 
@@ -274,7 +275,8 @@ def test_model_chunked_encdec_encodes_only_first_chunk_rows():
     first-chunk row mixed into three resuming rows must encode a batch
     of exactly that one row's frames (the old gate re-encoded all four
     whenever any row was at chunk 0), and the scattered cross-K/V must
-    leave every row's logits identical to the monolithic prefill."""
+    leave every row's logits identical to the monolithic prefill.  The
+    gate is `cross_gate`'s, as the engine computes it from the starts."""
     cfg = get_smoke("whisper-medium")
     model = build_model(cfg)
     params = model.init_params(jax.random.PRNGKey(0))
@@ -303,18 +305,20 @@ def test_model_chunked_encdec_encodes_only_first_chunk_rows():
     # engine's padding rows — it must NOT count as a first-chunk row
     b1 = np.zeros((B, 4), np.int32)
     b1[1:] = tn[1:, :4]
+    s1 = np.asarray([1, 0, 0, 0], np.int32)
     _, st = model.prefill_chunk(
-        params, jnp.asarray(b1), st,
-        q_start=jnp.asarray([1, 0, 0, 0], jnp.int32),
-        q_lens=jnp.asarray([0, 4, 4, 4], jnp.int32), extra=extra)
+        params, jnp.asarray(b1), st, q_start=jnp.asarray(s1),
+        q_lens=jnp.asarray([0, 4, 4, 4], jnp.int32), extra=extra,
+        **cross_gate(s1))
     # call 2: row 0's first (and only) chunk mixed into three resumes
     b2 = np.zeros((B, 4), np.int32)
     b2[0] = tn[0, :4]
     b2[1:] = tn[1:, 4:]
+    s2 = np.asarray([0, 4, 4, 4], np.int32)
     lg, st = model.prefill_chunk(
-        params, jnp.asarray(b2), st,
-        q_start=jnp.asarray([0, 4, 4, 4], jnp.int32),
-        q_lens=jnp.asarray([4, 4, 4, 4], jnp.int32), extra=extra)
+        params, jnp.asarray(b2), st, q_start=jnp.asarray(s2),
+        q_lens=jnp.asarray([4, 4, 4, 4], jnp.int32), extra=extra,
+        **cross_gate(s2))
     del model.encode
     assert enc_batches == [3, 1], (
         f"encoder batches {enc_batches}: per-row gate must encode only "
@@ -330,6 +334,99 @@ def test_model_chunked_rejects_recurrent():
         model.prefill_chunk(params, jnp.zeros((1, 4), jnp.int32), {},
                             jnp.zeros((1,), jnp.int32),
                             jnp.full((1,), 4, jnp.int32))
+
+
+# ---------------------------------------------------------------------------
+# the engine's compiled chunk step == eager model.prefill_chunk
+# ---------------------------------------------------------------------------
+JIT_CASES = {
+    "dense": lambda: get_smoke("llama2-7b"),
+    "windowed": lambda: get_smoke("llama2-7b").replace(layer_pattern="AW",
+                                                       window=12),
+    "moe_dropless": lambda: get_smoke("granite-moe-1b-a400m").replace(
+        moe_capacity=0.0),
+    "vlm": lambda: get_smoke("llama-3.2-vision-11b"),
+    "encdec": lambda: get_smoke("whisper-medium"),
+}
+# (q_start, q_lens) of successive calls over three rows, chunk 5: every
+# row at chunk 0, then all resuming (the last one dead, posing as a resume
+# as the engine's padding rows do), then a new first chunk mixed into two
+# resumes — for cross-attention models the gate's "full", "reuse" and
+# "partial" programs in turn
+JIT_CALLS = [([0, 0, 0], [5, 5, 3]), ([5, 5, 3], [5, 2, 0]),
+             ([10, 0, 3], [4, 5, 5])]
+
+
+@pytest.mark.parametrize("case", sorted(JIT_CASES))
+def test_jitted_chunk_step_matches_eager(case):
+    """The engine's jitted chunk program (``_jit_chunk``) returns the same
+    logits and pools as eager ``model.prefill_chunk`` on the same inputs,
+    call after call, with the cross-attention gate decided on the host."""
+    cfg = JIT_CASES[case]()
+    B, C = 3, 5
+    eng = Engine(cfg, max_slots=B, max_seq_len=64, prefill_chunk=C,
+                 rng=jax.random.PRNGKey(2))
+    model, params = eng.model, eng.params
+    ctx = None
+    if cfg.family == "vlm":
+        ctx = {"image_embeds": jax.random.normal(
+            jax.random.PRNGKey(4), (B, cfg.n_image_tokens, cfg.d_vision))}
+    elif cfg.family == "encdec":
+        ctx = {"frames": jax.random.normal(
+            jax.random.PRNGKey(4), (B, cfg.n_audio_frames, cfg.d_model))}
+    st = _mk_state(model, cfg, B)
+    rng = np.random.RandomState(3)
+    for i, (starts, lens) in enumerate(JIT_CALLS):
+        # a context scaled anew on every call makes the gate observable:
+        # a program that recomputed the cross K/V of resuming rows would
+        # read the new scale where eager keeps the cached rows
+        extra = (None if ctx is None
+                 else {k: v * (i + 1) for k, v in ctx.items()})
+        starts = np.asarray(starts, np.int32)
+        lens = np.asarray(lens, np.int32)
+        toks = jnp.asarray(rng.randint(0, cfg.vocab_size, (B, C)), jnp.int32)
+        gate = cross_gate(starts) if "cross_k" in st else {}
+        lg_j, st_j = eng._jit_chunk(params, toks, st, jnp.asarray(starts),
+                                    jnp.asarray(lens), extra, **gate)
+        lg_e, st_e = model.prefill_chunk(
+            params, toks, st, q_start=jnp.asarray(starts),
+            q_lens=jnp.asarray(lens), extra=extra, impl=eng.impl, **gate)
+        assert_close(lg_j, lg_e, rtol=1e-5, atol=1e-5)
+        for key in ("k_pages", "v_pages", "cross_k", "cross_v"):
+            if key in st_e:
+                assert_close(st_j[key], st_e[key], rtol=1e-5, atol=1e-5)
+        st = dict(st_e)
+    # one program per static gate: one for models without cross layers
+    modes = {cross_gate(np.asarray(s))["cross_mode"] for s, _ in JIT_CALLS}
+    assert eng.stats["chunk_traces"] == (len(modes) if "cross_k" in st
+                                         else 1)
+
+
+def test_engine_chunk_step_compiles_once():
+    """Varying occupancy (staggered arrivals, rows finishing) and ragged
+    final chunks all run the one compiled chunk program: the engine
+    traces it once and dispatches it once per chunk step."""
+    cfg = get_smoke("llama2-7b")
+    eng = Engine(cfg, max_slots=3, max_seq_len=64, prefill_chunk=8,
+                 rng=jax.random.PRNGKey(1))
+    arrivals = {0: [[3] * 13, [5] * 2], 2: [[7] * 21], 5: [[9] * 6, [4] * 17]}
+    reqs = []
+    chunk_steps = 0
+    for t in range(300):
+        for p in arrivals.get(t, []):
+            reqs.append(Request(prompt=list(p), max_new_tokens=4))
+            eng.add_request(reqs[-1])
+        if t > max(arrivals) and all(r.done for r in reqs):
+            break
+        before = sum(r.prefill_pos for r in reqs)
+        eng.step()
+        chunk_steps += sum(r.prefill_pos for r in reqs) > before
+    assert all(r.done for r in reqs)
+    rep = eng.robustness_report()
+    assert chunk_steps >= 5
+    assert rep["chunk_steps"] == chunk_steps
+    assert rep["chunk_traces"] == 1
+    assert eng.mgr.used_pages == 0
 
 
 # ---------------------------------------------------------------------------

@@ -151,3 +151,24 @@ def test_engine_decode_step_compiles(one_chip):
     assert "tpu_custom_call" in text
     # the name decode_attn_roofline reads the decode kernel's time by
     assert "paged_attention" in kernel_names(text)
+
+
+def test_engine_chunk_step_compiles(one_chip):
+    """The engine's jitted chunk-prefill program at its fixed
+    ``(max_slots, prefill_chunk)`` shape (depth cut to 2 layers)."""
+    cfg = get_config("granite-8b").replace(n_layers=2)
+    params = build_model(cfg).abstract_params(jnp.bfloat16)
+    C = 512
+    eng = Engine(cfg, params, impl="pallas", interpret=False,
+                 dtype=jnp.bfloat16, max_slots=B, max_seq_len=2048,
+                 pool_tokens=4096, prefill_chunk=C)
+    st = {"pos": eng.state["pos"], "k_pages": eng.state["k_pages"],
+          "v_pages": eng.state["v_pages"], "tables": eng._tables_array()}
+    row = jnp.zeros((B,), jnp.int32)
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (params, jnp.zeros((B, C), jnp.int32), st, row, row))
+    text = eng._jit_chunk.lower(*args, None).compile().as_text()
+    # the name prefill_attn_roofline reads the prefill kernel's time by,
+    # now inside the one program that holds the whole chunk step
+    assert "paged_prefill" in kernel_names(text)
