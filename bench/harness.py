@@ -6,11 +6,12 @@ tiny size while ``serve.py`` refuses to run anywhere but on a TPU.
 
 Clients and timing (all on the host's clock, ``time.perf_counter``):
 
-- set-up draws the weights, builds the engine, prefills the shared
-  documents of the mix (if any), compiles the per-row programs for every
-  row count, then runs the mix's own traffic until every slot has been
-  busy and a request has finished; so the traffic is in steady state when
-  the window opens;
+- set-up draws the weights and builds the engine through the
+  configuration's architecture module (``bench/arch/``), prefills the
+  shared documents of the mix (if any), compiles the per-row programs for
+  every row count, then runs the mix's own traffic until every slot has
+  been busy and a request has finished; so the traffic is in steady state
+  when the window opens;
 - in the window clients keep sending; ``attempted`` counts the requests
   sent in it, ``failed`` those of them that were refused, ended FAILED,
   or had no first token when the drain gave up;
@@ -36,11 +37,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import arch
 import devtrace
-import flops
 import reference
 import weights
-from repro.configs.base import ModelConfig
 from repro.serving import Engine, Request, Status
 from repro.serving.sampler import SampleParams, sample
 from traffic import RequestSpec, Traffic
@@ -86,35 +86,6 @@ def load_cell(name: str) -> Cell:
 # ---------------------------------------------------------------------------
 # the system under test
 # ---------------------------------------------------------------------------
-def engine_config(config: Dict):
-    """The engine's ``ModelConfig`` for a configuration file.  The engine
-    fixes some of the arithmetic (RMSNorm epsilon 1e-6, unit multipliers,
-    attention scale 1/sqrt(head_dim), no biases); a file that states other
-    values cannot be run as stated and is refused."""
-    m = config["model"]
-    hd = m.get("head_dim") or m["hidden_size"] // m["num_attention_heads"]
-    fixed = {"rms_norm_eps": 1e-6, "embedding_multiplier": 1.0,
-             "residual_multiplier": 1.0, "logits_scaling": 1.0,
-             "attention_multiplier": hd ** -0.5,
-             "attention_bias": False, "mlp_bias": False}
-    for k, v in fixed.items():
-        if k in m and not np.isclose(m[k], v, rtol=1e-6, atol=0):
-            raise ValueError(f"{config['name']}: the engine runs {k}={v}, "
-                             f"the file states {m[k]}")
-    experts = m.get("num_local_experts", 0)
-    return ModelConfig(
-        name=config["name"], family="moe" if experts else "dense",
-        n_layers=m["num_hidden_layers"], d_model=m["hidden_size"],
-        n_heads=m["num_attention_heads"],
-        n_kv_heads=m["num_key_value_heads"], head_dim=hd,
-        d_ff=m["intermediate_size"], vocab_size=m["vocab_size"],
-        activation="silu", rope_theta=float(m["rope_theta"]),
-        tie_embeddings=True, n_experts=experts,
-        top_k=m.get("num_experts_per_tok", 0),
-        d_ff_expert=m["intermediate_size"] if experts else 0,
-        moe_capacity=0.0, page_size=config["serving"]["page_size"])
-
-
 def spanned_engine_class(base=Engine):
     """The engine with a profiler span around each of its phases that
     exists (``SPANNED``), named ``engine.<phase>``.  The sampling phase
@@ -362,7 +333,7 @@ def pick_checked(recs: List[Rec], n: int, seed: int,
     return [longest] + [rest[i] for i in sorted(chosen)]
 
 
-def check(arch: reference.Arch, w: Dict, checked: List[Rec],
+def check(model, a, w: Dict, checked: List[Rec],
           served_logits: Optional[Dict[int, List[np.ndarray]]],
           control: bool) -> Dict:
     """Over every output token of the checked requests: the widest gap
@@ -373,18 +344,19 @@ def check(arch: reference.Arch, w: Dict, checked: List[Rec],
     which no limit holds, when the engine's logits were not kept or a
     checked request lacks a row for any of its tokens.  With ``control``,
     the same two numbers for the fp8 reference put in the engine's place
-    (``control_gap``, ``control_dev``)."""
+    (``control_gap``, ``control_dev``).  ``model`` is the architecture
+    module (``arch.for_model``), ``a`` its ``reference`` sizes and ``w``
+    the weights drawn."""
     got = {"served_gap": 0.0, "logit_dev": 0.0, "tokens_compared": 0,
            "rows_missing": 0}
     if control:
         got.update(control_gap=0.0, control_dev=0.0)
-    V = int(w["embed"].shape[0])
     for rec in checked:
         prompt, out = rec.req.prompt, list(rec.req.output)
         n, first = len(out), len(prompt) - 1
-        x = reference.forward(arch, w, list(prompt) + out[:-1], first)
-        ref = reference.logits(arch, w, x, first)
-        prog = np.zeros((reference.OUT_BLOCK, V), np.float32)
+        x = model.forward(a, w, list(prompt) + out[:-1], first)
+        ref = model.logits(a, w, x, first)
+        prog = np.zeros(ref.shape, np.float32)
         rows = (served_logits or {}).get(rec.req.rid, [])
         if len(rows) == n:
             prog[:n] = np.stack(rows)
@@ -396,8 +368,8 @@ def check(arch: reference.Arch, w: Dict, checked: List[Rec],
             got["logit_dev"] = max(got["logit_dev"], r["dev"])
         got["tokens_compared"] += n
         if control:
-            q = reference.logits(arch, w, reference.forward(
-                arch, w, list(prompt) + out[:-1], first, quant=True),
+            q = model.logits(a, w, model.forward(
+                a, w, list(prompt) + out[:-1], first, quant=True),
                 first, quant=True)
             top = np.asarray(jnp.argmax(q, axis=-1))[:n]
             c = reference.compare(ref, top, q, n)
@@ -465,7 +437,7 @@ class RunView:
     steps: List[StepRec]  # steps of the window
     w0: float
     w1: float
-    shape: flops.Shape
+    shape: arch.Shape
     peak: Dict
     counters: Dict[str, Dict[str, int]]  # "before"/"after" the window
     trace: Optional[devtrace.Trace]  # the traced window, or None
@@ -511,8 +483,9 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     serving = config["serving"]
     dev = jax.devices()[0]
     peak = peak or load_peak(dev.device_kind)
-    shape = flops.Shape.from_model(config["model"])
-    arch = reference.Arch.from_model(config["model"])
+    model = arch.for_model(config["model"])
+    shape = model.shape(config["model"])
+    ref_arch = model.reference(config["model"])
     dtype = jnp.dtype(config["model"]["torch_dtype"])
 
     def phase(label, fn, *args):
@@ -525,10 +498,10 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
             f"compiled) in {c.seconds:.3f} s")
         return out
 
-    w = phase("weights", weights.draw, shape, seed, dtype)
+    w = phase("weights", model.draw, shape, seed, dtype)
     cls = spanned_engine_class(engine_base)
     eng = phase("engine", lambda: cls(
-        engine_config(config), weights.to_engine(w, shape),
+        model.engine_config(config), model.to_engine(w, shape),
         max_slots=serving["max_slots"], max_seq_len=serving["max_seq_len"],
         pool_tokens=serving["pool_tokens"], impl=serving["impl"],
         rng=weights.seed_key(seed + 1), dtype=dtype,
@@ -587,7 +560,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     gc.collect()
 
     t = time.perf_counter()
-    got = check(arch, w, checked, served_logits, control)
+    got = check(model, ref_arch, w, checked, served_logits, control)
     log(f"reference check: {time.perf_counter() - t:.3f} s")
     del w
     if control:

@@ -1,12 +1,10 @@
 """Model FLOP utilization of the decode-only steps, in percent.
 
 Useful FLOPs of the rows those steps decoded (the active parameters and
-attention over each row's live context, ``flops.decode_flops``) over
-their wall time on the host clock times the chip's bf16 peak.  Should
-move ``itl_p50_ms``.
+attention over each row's live context, ``Shape.decode_flops`` of the
+architecture's module under ``bench/arch/``) over their wall time on the
+host clock times the chip's bf16 peak.  Should move ``itl_p50_ms``.
 """
-
-import flops
 
 
 def reduce(run):
@@ -14,6 +12,6 @@ def reduce(run):
     wall = sum(s.wall for s in steps)
     if not wall:
         return None
-    work = sum(flops.decode_flops(run.shape, c)
+    work = sum(run.shape.decode_flops(c)
                for s in steps for c in s.decode_ctx)
     return 100.0 * work / (wall * run.peak["bf16_flops_per_s"])

@@ -2,12 +2,10 @@
 percent.
 
 Useful FLOPs of the live prompt tokens those steps prefilled
-(``flops.prefill_flops``, causal over the cached prefix) over their wall
+(``Shape.prefill_flops``, causal over the cached prefix) over their wall
 time on the host clock times the chip's bf16 peak.  The decode rows those
 steps also carry are not counted.  Should move ``ttft_p50_ms``.
 """
-
-import flops
 
 
 def reduce(run):
@@ -15,6 +13,6 @@ def reduce(run):
     wall = sum(s.wall for s in steps)
     if not wall:
         return None
-    work = sum(flops.prefill_flops(run.shape, a, n)
+    work = sum(run.shape.prefill_flops(a, n)
                for s in steps for a, n in s.chunk_rows)
     return 100.0 * work / (wall * run.peak["bf16_flops_per_s"])

@@ -5,13 +5,11 @@ over the window's length on the host clock times the chip's bf16 peak.
 Bounds every kernel's share from above.  Should move ``output_tok_per_s``.
 """
 
-import flops
-
 
 def reduce(run):
-    work = sum(flops.decode_flops(run.shape, c)
+    work = sum(run.shape.decode_flops(c)
                for s in run.steps for c in s.decode_ctx)
-    work += sum(flops.prefill_flops(run.shape, a, n)
+    work += sum(run.shape.prefill_flops(a, n)
                 for s in run.steps for a, n in s.chunk_rows)
     if not run.steps:
         return None

@@ -16,6 +16,7 @@ BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
 sys.path.insert(0, str(BENCH))
 
+import arch  # noqa: E402
 import harness  # noqa: E402
 
 CELLS = [w["name"] for w in json.loads(
@@ -31,14 +32,7 @@ TINY_DEV = 0.015
 
 def tiny_config(c):
     c = copy.deepcopy(c)
-    m = c["model"]
-    m.update(hidden_size=128, num_attention_heads=4, num_key_value_heads=2,
-             head_dim=32, intermediate_size=128, num_hidden_layers=2,
-             vocab_size=256)
-    if m.get("num_local_experts"):
-        m.update(num_local_experts=4, num_experts_per_tok=2)
-    if "attention_multiplier" in m:
-        m["attention_multiplier"] = 32 ** -0.5
+    c["model"] = arch.for_model(c["model"]).tiny(c["model"])
     c["serving"].update(max_slots=2, pool_tokens=1024, page_size=8,
                         prefill_chunk=32, max_seq_len=256, impl="ref")
     c["check"] = {"served_gap": TINY_LIMIT, "logit_dev": TINY_DEV,
@@ -136,19 +130,16 @@ def test_fp8_control_fails_the_check():
 
     import numpy as np
 
-    import flops
-    import reference
-    import weights
     cfg = tiny_config(harness.load_cell(CELLS[0]).config)
     cfg["model"].update(hidden_size=256, head_dim=64, intermediate_size=512,
                         vocab_size=4096)
-    shape = flops.Shape.from_model(cfg["model"])
-    w = weights.draw(shape, 5)
+    model = arch.for_model(cfg["model"])
+    w = model.draw(model.shape(cfg["model"]), 5)
     rng = np.random.default_rng(5)
     recs = [types.SimpleNamespace(req=types.SimpleNamespace(
         rid=i, prompt=rng.integers(0, 4096, 64).tolist(),
         output=rng.integers(0, 4096, 250).tolist())) for i in range(4)]
-    got = harness.check(reference.Arch.from_model(cfg["model"]), w, recs,
+    got = harness.check(model, model.reference(cfg["model"]), w, recs,
                         None, control=True)
     assert got["tokens_compared"] == 1000
     assert got["control_gap"] > TINY_LIMIT
@@ -172,40 +163,38 @@ def _tiny_check_inputs(n_reqs=2, n_out=20):
 
     import numpy as np
 
-    import flops
-    import reference
-    import weights
     cfg = tiny_config(harness.load_cell(CELLS[0]).config)
-    shape = flops.Shape.from_model(cfg["model"])
-    w = weights.draw(shape, 3)
-    arch = reference.Arch.from_model(cfg["model"])
+    model = arch.for_model(cfg["model"])
+    shape = model.shape(cfg["model"])
+    w = model.draw(shape, 3)
+    a = model.reference(cfg["model"])
     rng = np.random.default_rng(3)
     recs, rows = [], {}
     for rid in range(n_reqs):
         prompt = rng.integers(0, shape.vocab, 16).tolist()
         out = rng.integers(0, shape.vocab, n_out).tolist()
-        x = reference.forward(arch, w, prompt + out[:-1], len(prompt) - 1)
-        lg = np.asarray(reference.logits(arch, w, x, len(prompt) - 1))
+        x = model.forward(a, w, prompt + out[:-1], len(prompt) - 1)
+        lg = np.asarray(model.logits(a, w, x, len(prompt) - 1))
         rows[rid] = list(lg[:n_out])
         recs.append(types.SimpleNamespace(req=types.SimpleNamespace(
             rid=rid, prompt=prompt, output=out)))
-    return arch, w, recs, rows
+    return (model, a), w, recs, rows
 
 
 @pytest.mark.parametrize("lost", ["no_hook", "row_missing"])
 def test_unread_logit_dev_is_not_correct(lost):
     """A configuration that holds ``logit_dev`` fails a run that could not
     read it: no logits kept at all, or a checked request short of a row."""
-    arch, w, recs, rows = _tiny_check_inputs()
+    ref, w, recs, rows = _tiny_check_inputs()
     limits = {"logit_dev": TINY_DEV}
     checks, passed = harness.held(
-        harness.check(arch, w, recs, rows, control=False), limits)
+        harness.check(*ref, w, recs, rows, control=False), limits)
     assert passed and checks["logit_dev"]["value"] < 1e-6, checks
     if lost == "no_hook":
         rows = None
     else:
         rows[recs[1].req.rid] = rows[recs[1].req.rid][:-1]
-    got = harness.check(arch, w, recs, rows, control=False)
+    got = harness.check(*ref, w, recs, rows, control=False)
     checks, passed = harness.held(got, limits)
     assert checks["logit_dev"]["value"] is None
     assert passed is False

@@ -12,14 +12,15 @@ import pytest
 BENCH = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(BENCH))
 
+import arch  # noqa: E402
 import devtrace  # noqa: E402
-import flops  # noqa: E402
 import harness  # noqa: E402
 
 DATA = Path(__file__).resolve().parent / "data"
 PEAK = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
-SHAPE = flops.Shape(d=1024, layers=24, heads=16, kv_heads=8, head_dim=64,
-                    ff=512, vocab=49155, experts=32, top_k=8)
+MOE = json.loads((BENCH / "configs" / "granite-moe-1b-a400m.json")
+                 .read_text())["model"]
+SHAPE = arch.for_model(MOE).shape(MOE)
 METRICS = [m["name"] for m in json.loads(
     (BENCH.parent / "BENCHMARK.json").read_text())["per_layer"]]
 
@@ -90,7 +91,7 @@ def test_step_metrics():
     assert r["chunk_step_ms"] == pytest.approx(1000.0)
     assert r["decode_batch_mean"] == pytest.approx(2.5)
     assert r["prefix_hit_share"] == pytest.approx(50.0)
-    want = sum(flops.decode_flops(SHAPE, c) for c in (101, 201, 50))
+    want = sum(SHAPE.decode_flops(c) for c in (101, 201, 50))
     assert r["mfu.decode"] == pytest.approx(
         100 * want / (0.1 * PEAK["bf16_flops_per_s"]))
     assert 0 < r["mfu.prefill"] < 100 and 0 < r["mfu"] < 100
